@@ -43,7 +43,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import csv_row
-from repro.analysis.roofline import HBM_BW, LINK_BW
+from repro.analysis.roofline import V5E, peaks
+HBM_BW, LINK_BW = peaks(V5E).hbm_bw, peaks(V5E).link_bw
 from repro.core.histogram import _local_probe, _local_probe_batch
 
 

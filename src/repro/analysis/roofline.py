@@ -2,7 +2,7 @@
 
     compute_term    = HLO_FLOPs / (chips x peak_FLOPs)      [s]
     memory_term     = HLO_bytes / (chips x HBM_bw)          [s]
-    collective_term = wire_bytes / (chips x link_bw)        [s]
+    collective_term = wire_bytes / (chips x link_bw)        [s]   (one link)
 
 ``cost_analysis()`` on the post-SPMD module is *per device*, so chips=1 in the
 denominators here and the table reports per-chip seconds directly.
@@ -12,8 +12,9 @@ apply ring-algorithm wire formulas per op kind (documented inline). Group size
 is parsed from ``replica_groups`` (both the explicit ``{{0,1,...}}`` and the
 iota ``[G,S]<=[N]`` forms).
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI (per the assignment).
+Hardware peaks come from one table, ``PEAKS``, keyed by the device kind
+JAX reports (``jax.devices()[0].device_kind``), each row with its source. A
+kind that is not in the table is an error, never a default.
 """
 
 from __future__ import annotations
@@ -23,9 +24,44 @@ import json
 import re
 from typing import Any
 
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-LINK_BW = 50e9               # bytes/s per ICI link (assignment constant)
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks."""
+
+    flops: float     # bf16 FLOP/s
+    hbm_bw: float    # HBM bytes/s
+    ici_bw: float    # chip-to-chip interconnect bytes/s, all links together
+    ici_links: int   # interconnect links per chip
+    source: str
+
+    @property
+    def link_bw(self) -> float:
+        """Bytes/s over one interconnect link: the ring formulas below
+        count the bytes one link carries."""
+        return self.ici_bw / self.ici_links
+
+
+V5E = "TPU v5 lite"  # device_kind of a TPU v5e chip
+
+PEAKS = {
+    V5E: ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8,
+                   ici_links=4,
+                   source="Google Cloud documentation, 'TPU v5e': 197 "
+                          "TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 "
+                          "Gbit/s chip-to-chip interconnect over 4 ICI "
+                          "ports per chip (2D torus)"),
+}
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """The peaks of ``device_kind``; raises for a kind not in ``PEAKS``."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; add them to PEAKS with their "
+                       f"source") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -133,13 +169,15 @@ def analyze(
 ) -> Roofline:
     """Roofline terms from post-SPMD HLO via the loop-aware structural model
     (repro.analysis.hlo_cost) — ``cost_analysis()`` counts while bodies once,
-    so it cannot be used directly for scanned models."""
+    so it cannot be used directly for scanned models. The terms are seconds
+    on one v5e chip: a dry run models a v5e deployment."""
     from repro.analysis.hlo_cost import analyze_hlo
 
+    pk = peaks(V5E)
     c = analyze_hlo(hlo_text, default_group=default_group)
-    ct = c.flops / PEAK_FLOPS
-    mt = c.hbm_bytes / HBM_BW
-    lt = c.wire_bytes / LINK_BW
+    ct = c.flops / pk.flops
+    mt = c.hbm_bytes / pk.hbm_bw
+    lt = c.wire_bytes / pk.link_bw
     terms = {"compute": ct, "memory": mt, "collective": lt}
     bottleneck = max(terms, key=terms.get)
     return Roofline(

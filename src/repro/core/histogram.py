@@ -13,10 +13,11 @@ probe primitives are:
     the store once per batch turns B bandwidth-bound matvecs into a single
     (N, d) x (d, B) MXU matmul — ~B× less HBM traffic per predicate.
 
-All probes are a single fused pass over the store (cosine distances never
-materialize at full precision off-chip): on TPU via the ``cosine_topk``
-Pallas kernels (B-tiled for coalesced batches with B >> 128), on this CPU
-container via the jnp reference. Distributed: each shard counts/top-ks
+All probes are a single pass over the store: ``impl="pallas"`` runs the
+fused ``cosine_topk`` kernels (B-tiled for coalesced batches with
+B >> 128; compiled on a TPU, interpreted elsewhere), ``impl="xla"`` the
+jnp twins. Every distance contraction runs at ``Precision.HIGHEST``, so a
+TPU's MXU keeps f32 accuracy. Distributed: each shard counts/top-ks
 locally, then one tiny ``psum``/gather combines — the probe's collective
 traffic is O(B*k), independent of N.
 
@@ -69,13 +70,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.cosine_topk.ref import HIGHEST
+
 f32 = jnp.float32
 
 
 def _local_probe(store, pred, thresholds, k):
     """store (n,d) f32/bf16; pred (d,); thresholds (t,). Returns
     (counts (t,), smallest_k (k,)) — one pass, fused."""
-    sims = jnp.einsum("nd,d->n", store.astype(f32), pred.astype(f32))
+    sims = jnp.einsum("nd,d->n", store.astype(f32), pred.astype(f32),
+                      precision=HIGHEST)
     dists = 1.0 - sims
     counts = (dists[None, :] <= thresholds[:, None]).sum(axis=1)
     neg_top, _ = jax.lax.top_k(-dists, k)
@@ -85,7 +89,8 @@ def _local_probe(store, pred, thresholds, k):
 def _local_probe_batch(store, preds, thresholds, k):
     """store (n,d); preds (B,d); thresholds (B,t). Returns
     (counts (B,t), smallest_k (B,k)) — one store pass for all B predicates."""
-    sims = jnp.einsum("nd,bd->bn", store.astype(f32), preds.astype(f32))
+    sims = jnp.einsum("nd,bd->bn", store.astype(f32), preds.astype(f32),
+                      precision=HIGHEST)
     dists = 1.0 - sims                                      # (B, n)
     counts = (dists[:, None, :] <= thresholds[:, :, None]).sum(axis=-1)
     neg_top, _ = jax.lax.top_k(-dists, k)
@@ -99,7 +104,8 @@ def _masked_local_probe(store, n_valid, pred, thresholds, k):
     bitwise the distance ``_local_probe`` computes for that row in a full
     scan — the invariant the pruned sharded path's parity rests on. Dead
     rows score +inf (never counted, never in the top-k)."""
-    sims = jnp.einsum("nd,d->n", store.astype(f32), pred.astype(f32))
+    sims = jnp.einsum("nd,d->n", store.astype(f32), pred.astype(f32),
+                      precision=HIGHEST)
     dists = jnp.where(jnp.arange(store.shape[0]) < n_valid,
                       1.0 - sims, jnp.inf)
     counts = (dists[None, :] <= thresholds[:, None]).sum(axis=1)
@@ -111,7 +117,8 @@ def _masked_local_probe_batch(store, n_valid, preds, thresholds, k):
     """Batched twin of ``_masked_local_probe`` (mirrors the ``nd,bd->bn``
     contraction of ``_local_probe_batch`` so pruned batched scans stay
     bitwise the full batched scan's per-row distances)."""
-    sims = jnp.einsum("nd,bd->bn", store.astype(f32), preds.astype(f32))
+    sims = jnp.einsum("nd,bd->bn", store.astype(f32), preds.astype(f32),
+                      precision=HIGHEST)
     dists = jnp.where(jnp.arange(store.shape[0])[None, :] < n_valid,
                       1.0 - sims, jnp.inf)
     counts = (dists[:, None, :] <= thresholds[:, :, None]).sum(axis=-1)
@@ -545,7 +552,8 @@ class SemanticHistogram:
         For a mutable index: distances of the *live* rows."""
         if self._mutable:
             return self.index.distances(pred)
-        sims = self.embeddings.astype(f32) @ jnp.asarray(pred, f32)
+        sims = jnp.matmul(self.embeddings.astype(f32), jnp.asarray(pred, f32),
+                          precision=HIGHEST)
         return np.asarray(1.0 - sims)
 
 
@@ -558,7 +566,7 @@ def _mesh_data_axes(mesh) -> tuple[str, ...]:
 
 
 def make_sharded_probe(mesh, *, k: int = 128, batched: bool = False,
-                       impl: str = "xla", interpret: bool = True):
+                       impl: str = "xla", interpret: bool | None = None):
     """shard_map probe over a ('pod','data')-sharded store: local fused pass,
     psum of counts, all-gather + resort of per-shard top-k. Used by the probe
     scaling benchmark and the multi-pod serve path.
@@ -576,7 +584,6 @@ def make_sharded_probe(mesh, *, k: int = 128, batched: bool = False,
     count, so ``k`` may exceed the per-shard rows (threshold calibration
     asks for k up to N); the merged result is still the exact global top-k.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     data_axes = _mesh_data_axes(mesh)
@@ -611,17 +618,17 @@ def make_sharded_probe(mesh, *, k: int = 128, batched: bool = False,
         flat = jnp.moveaxis(gathered, 0, 1).reshape(local_top.shape[0], -1)
         return counts, -jax.lax.top_k(-flat, min(k, flat.shape[1]))[0]
 
-    return shard_map(
+    return jax.shard_map(
         probe_batch if batched else probe, mesh=mesh,
         in_specs=(P(data_axes), P(), P()),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
 
 
 def make_sharded_pruned_probe(mesh, index, *, k: int = 128,
                               batched: bool = False, impl: str = "xla",
-                              interpret: bool = True, store=None):
+                              interpret: bool | None = None, store=None):
     """Cluster-pruned twin of ``make_sharded_probe`` — sublinear per shard.
 
     ``index`` is a ``repro.index.ShardedClusteredStore`` whose shard blocks
@@ -658,7 +665,6 @@ def make_sharded_pruned_probe(mesh, index, *, k: int = 128,
     same reason ``ClusteredStore._gather`` runs its ``jnp.take`` eagerly
     outside the jitted masked probe.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     data_axes = _mesh_data_axes(mesh)
@@ -675,10 +681,10 @@ def make_sharded_pruned_probe(mesh, index, *, k: int = 128,
         store = jax.device_put(index.embeddings,
                                NamedSharding(mesh, P(data_axes)))
 
-    gather = jax.jit(shard_map(
+    gather = jax.jit(jax.shard_map(
         lambda store_l, idx_l: jnp.take(store_l, idx_l[0], axis=0),
         mesh=mesh, in_specs=(P(data_axes), P(data_axes)),
-        out_specs=P(data_axes), check_rep=False,
+        out_specs=P(data_axes), check_vma=False,
     ))
 
     def body(buf, nv_l, extra_l, preds, thr):
@@ -705,11 +711,11 @@ def make_sharded_pruned_probe(mesh, index, *, k: int = 128,
         flat = jax.lax.all_gather(top, data_axes, tiled=True)   # (S*kk,)
         return counts, -jax.lax.top_k(-flat, k_final)[0]
 
-    sharded = jax.jit(shard_map(
+    sharded = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(data_axes), P(data_axes), P(data_axes), P(), P()),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     ))
 
     def probe(preds, thresholds, *, need_topk: bool = True, live=None,

@@ -155,29 +155,34 @@ def make_corpus(
     rng.shuffle(w)
     w /= w.sum()
     counts = rng.multinomial(n_images, w)
-    images, image_leaf = [], []
+    # one (cnt, d) draw per leaf: the Generator's stream is the same as cnt
+    # draws of (d,), and each row is normalized by its own 1-D norm, so the
+    # corpus is bitwise the row-at-a-time one without a float64 row list
+    images = np.empty((n_images, dim), np.float32)
+    image_leaf = np.repeat(np.asarray(leaves, np.int64), counts)
+    noise_scale = p["img_noise"] / np.sqrt(dim)
+    pos = 0
     for leaf, cnt in zip(leaves, counts):
-        base = concepts[leaf].direction
-        noise_scale = p["img_noise"] / np.sqrt(dim)
-        for _ in range(cnt):
-            v = base + noise_scale * rng.standard_normal(dim)
-            images.append(v / np.linalg.norm(v))
-            image_leaf.append(leaf)
-    images = np.asarray(images, np.float32)
-    image_leaf = np.asarray(image_leaf, np.int64)
+        v = rng.standard_normal((cnt, dim))
+        v *= noise_scale                   # in place: same bits as
+        v += concepts[leaf].direction      # direction + scale * z
+        # np.linalg.norm of a 1-D row is sqrt(row.dot(row))
+        v /= np.sqrt([row.dot(row) for row in v])[:, None]
+        images[pos:pos + cnt] = v
+        pos += cnt
 
-    # fill subtree image id lists bottom-up
-    ids_by_leaf: dict[int, list[int]] = {}
-    for i, leaf in enumerate(image_leaf):
-        ids_by_leaf.setdefault(int(leaf), []).append(i)
+    # fill subtree image id lists bottom-up (each leaf's images are one
+    # contiguous row range)
+    ends = np.cumsum(counts)
+    ids_by_leaf = {leaf: np.arange(end - cnt, end, dtype=np.int64)
+                   for leaf, cnt, end in zip(leaves, counts, ends)}
 
-    def collect(nid) -> list[int]:
+    def collect(nid) -> np.ndarray:
         c = concepts[nid]
-        out = list(ids_by_leaf.get(nid, []))
-        for ch in c.children:
-            out.extend(collect(ch))
-        c.leaf_image_ids = np.asarray(sorted(out), np.int64)
-        return out
+        parts = [ids_by_leaf[nid]] if nid in ids_by_leaf else []
+        parts += [collect(ch) for ch in c.children]
+        c.leaf_image_ids = np.sort(np.concatenate(parts))
+        return c.leaf_image_ids
 
     collect(0)
     return Corpus(name=name, dim=dim, images=images, image_leaf=image_leaf,

@@ -59,7 +59,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.cosine_topk.ref import cosine_probe_batch_masked_ref
+from repro.kernels.cosine_topk.ref import (
+    HIGHEST,
+    cosine_probe_batch_masked_ref,
+)
 from repro.kernels.kmeans.ops import kmeans
 
 f32 = jnp.float32
@@ -90,7 +93,8 @@ def _compound_masked_xla(store, n_valid, preds, thr, *, mode: str):
     (padding) rows score +inf for every conjunct, so they match nothing
     under either mode.
     """
-    sims = jnp.einsum("nd,bd->bn", store.astype(f32), preds.astype(f32))
+    sims = jnp.einsum("nd,bd->bn", store.astype(f32), preds.astype(f32),
+                      precision=HIGHEST)
     dists = jnp.where(jnp.arange(store.shape[0])[None, :] < n_valid,
                       1.0 - sims, jnp.inf)
     match = dists <= thr[:, None]                       # (B, n)
@@ -104,7 +108,8 @@ def _masked_probe_xla(store, n_valid, pred, thr, *, k: int):
     einsum, so a pruned one-predicate scan is bitwise the full scalar scan.
     Deliberately NOT the batched ref at B=1: the scalar and batched einsum
     contractions may reduce in different orders on some XLA backends."""
-    sims = jnp.einsum("nd,d->n", store.astype(f32), pred.astype(f32))
+    sims = jnp.einsum("nd,d->n", store.astype(f32), pred.astype(f32),
+                      precision=HIGHEST)
     dists = jnp.where(jnp.arange(store.shape[0]) < n_valid,
                       1.0 - sims, jnp.inf)
     counts = (dists[None, :] <= thr[:, None]).sum(axis=1)
@@ -376,7 +381,8 @@ class ClusteredStore:
     # -------------------------------------------------------------- probe
 
     def probe_pruned(self, preds: np.ndarray, thresholds: np.ndarray, *,
-                     k: int = 1, impl: str = "xla", interpret: bool = True,
+                     k: int = 1, impl: str = "xla",
+                     interpret: bool | None = None,
                      scalar_kernel: bool = False, need_topk: bool = True,
                      live: np.ndarray | None = None,
                      live_sizes: np.ndarray | None = None,
@@ -576,7 +582,7 @@ class ClusteredStore:
         return count, stats
 
     def kth_smallest(self, pred: np.ndarray, k: int, *, impl: str = "xla",
-                     interpret: bool = True,
+                     interpret: bool | None = None,
                      live: np.ndarray | None = None,
                      live_sizes: np.ndarray | None = None) -> float:
         """Exact k-th smallest distance via bound-ordered cluster scanning.
@@ -657,6 +663,9 @@ class ClusteredStore:
                 self._cum[key] = 0
 
 
+_NORM_CHUNK = 65536
+
+
 def _assemble_store(x: np.ndarray, cent64: np.ndarray, assign: np.ndarray,
                     *, eps: float, chunk_rows: int,
                     perm_base: np.ndarray | None = None) -> ClusteredStore:
@@ -672,14 +681,21 @@ def _assemble_store(x: np.ndarray, cent64: np.ndarray, assign: np.ndarray,
     offsets = np.zeros(k + 1, np.int64)
     offsets[1:] = np.cumsum(sizes)
     xs = x[order]
-    rnorm = np.linalg.norm(xs.astype(np.float64) - cent64[assign[order]],
-                           axis=1)
+    a_sorted = assign[order]
+    # f64 norms a chunk of rows at a time: a whole-store f64 copy is twice
+    # the f32 store's host memory (~9.7 GB at 1M x 1152)
+    rnorm = np.empty(n, np.float64)
+    row_norm = 0.0 if n else 1.0
+    for s in range(0, n, _NORM_CHUNK):
+        blk = xs[s:s + _NORM_CHUNK].astype(np.float64)
+        rnorm[s:s + _NORM_CHUNK] = np.linalg.norm(
+            blk - cent64[a_sorted[s:s + _NORM_CHUNK]], axis=1)
+        row_norm = max(row_norm, float(np.linalg.norm(blk, axis=1).max()))
     radii = np.zeros(k, np.float64)
     for c in range(k):
         if sizes[c]:
             radii[c] = rnorm[offsets[c]:offsets[c + 1]].max()
     radii = radii * (1.0 + 1e-9) + 1e-12
-    row_norm = np.linalg.norm(xs.astype(np.float64), axis=1).max() if n else 1.0
     perm = order if perm_base is None else np.asarray(perm_base)[order]
     return ClusteredStore(
         embeddings=jnp.asarray(xs), offsets=offsets, sizes=sizes,
@@ -811,7 +827,7 @@ def _split_fat_clusters(x: np.ndarray, cent64: np.ndarray,
 
 def build_clustered_store(
     embeddings: np.ndarray, k_clusters: int, *, iters: int = 8,
-    seed: int = 0, impl: str = "pallas", interpret: bool = True,
+    seed: int = 0, impl: str = "pallas", interpret: bool | None = None,
     eps: float = 1e-4, chunk_rows: int = 4096,
     split_radius: float | None = None, max_clusters: int | None = None,
     init_centroids: np.ndarray | None = None,

@@ -60,6 +60,7 @@ import numpy as np
 
 from repro.index.clustered import build_clustered_store
 from repro.index.sharded import build_sharded_clustered_store
+from repro.kernels.cosine_topk.ref import HIGHEST
 
 f32 = jnp.float32
 
@@ -71,7 +72,8 @@ def _tail_probe_xla(store, mask, pred, thr, *, k: int):
     """Scalar rowmask tail scan — mirrors ``histogram._local_probe``'s
     ``nd,d->n`` contraction so tail rows' distances are bitwise the
     distances a fresh full scalar scan computes for them."""
-    sims = jnp.einsum("nd,d->n", store.astype(f32), pred.astype(f32))
+    sims = jnp.einsum("nd,d->n", store.astype(f32), pred.astype(f32),
+                      precision=HIGHEST)
     dists = jnp.where(mask != 0, 1.0 - sims, jnp.inf)
     counts = (dists[None, :] <= thr[:, None]).sum(axis=1)
     neg_top, _ = jax.lax.top_k(-dists, k)
@@ -81,7 +83,8 @@ def _tail_probe_xla(store, mask, pred, thr, *, k: int):
 @partial(jax.jit, static_argnames=("k",))
 def _tail_probe_batch_xla(store, mask, preds, thr, *, k: int):
     """Batched twin (``nd,bd->bn``, matching ``_local_probe_batch``)."""
-    sims = jnp.einsum("nd,bd->bn", store.astype(f32), preds.astype(f32))
+    sims = jnp.einsum("nd,bd->bn", store.astype(f32), preds.astype(f32),
+                      precision=HIGHEST)
     dists = jnp.where(mask[None, :] != 0, 1.0 - sims, jnp.inf)
     counts = (dists[:, None, :] <= thr[:, :, None]).sum(axis=-1)
     neg_top, _ = jax.lax.top_k(-dists, k)
@@ -93,7 +96,8 @@ def _tail_compound_xla(store, mask, preds, thr, *, mode: str):
     """Compound rowmask tail scan — same ``nd,bd->bn`` contraction as
     ``clustered._compound_masked_xla``, with tombstoned (and padding) rows
     masked to +inf so they match no conjunct under either mode."""
-    sims = jnp.einsum("nd,bd->bn", store.astype(f32), preds.astype(f32))
+    sims = jnp.einsum("nd,bd->bn", store.astype(f32), preds.astype(f32),
+                      precision=HIGHEST)
     dists = jnp.where(mask[None, :] != 0, 1.0 - sims, jnp.inf)
     match = dists <= thr[:, None]
     hit = match.all(axis=0) if mode == "and" else match.any(axis=0)
@@ -121,7 +125,7 @@ class MutableClusteredStore:
     is_mutable = True
 
     def __init__(self, embeddings: np.ndarray, k_clusters: int, *,
-                 mesh=None, impl: str = "xla", interpret: bool = True,
+                 mesh=None, impl: str = "xla", interpret: bool | None = None,
                  iters: int = 8, seed: int = 0,
                  split_radius: float | None = None,
                  max_clusters: int | None = None,
@@ -564,7 +568,8 @@ class MutableClusteredStore:
             rows = np.concatenate([self._base_emb_np[self._live],
                                    self._tail_emb[:self._tail_len]
                                    [self._tail_live[:self._tail_len]]])
-        sims = jnp.asarray(rows).astype(f32) @ jnp.asarray(pred, f32)
+        sims = jnp.matmul(jnp.asarray(rows).astype(f32),
+                          jnp.asarray(pred, f32), precision=HIGHEST)
         return np.asarray(1.0 - sims)
 
     # ------------------------------------------------------------- rebuild

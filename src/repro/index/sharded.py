@@ -61,7 +61,6 @@ import dataclasses
 import heapq
 import threading
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -89,7 +88,9 @@ class ShardedClusteredStore:
 
     shards: list[ClusteredStore]   # per-shard sub-index over its row block
     shard_rows: int                # rows per shard (uniform)
-    embeddings: jax.Array          # (N, d) f32, shard-blocked + reordered
+    embeddings: np.ndarray         # (N, d) f32, shard-blocked + reordered;
+    #                                kept on the host — the probe places it
+    #                                on the mesh, one row block per device
     perm: np.ndarray               # (N,) original row ids in stored order
     balance: str = "contiguous"    # partitioning strategy used at build
     # predicted per-shard boundary mass of the *contiguous* row-block
@@ -429,7 +430,7 @@ def _pack_boundary_incremental(
 def build_sharded_clustered_store(
     embeddings: np.ndarray, k_clusters: int, n_shards: int, *,
     iters: int = 8, seed: int = 0, impl: str = "pallas",
-    interpret: bool = True, eps: float = 1e-4, chunk_rows: int = 4096,
+    interpret: bool | None = None, eps: float = 1e-4, chunk_rows: int = 4096,
     balance: str = "contiguous", split_radius: float | None = None,
     max_clusters: int | None = None,
     init_centroids: np.ndarray | None = None,
@@ -518,7 +519,7 @@ def build_sharded_clustered_store(
             parts.append(np.asarray(cs.embeddings))
         return ShardedClusteredStore(
             shards=shards, shard_rows=rows,
-            embeddings=jnp.asarray(np.concatenate(parts)),
+            embeddings=np.concatenate(parts),
             perm=np.concatenate(perm), balance="boundary",
             contiguous_mass=contiguous_mass,
             global_centroids=np.asarray(gcs.centroids, np.float64))
@@ -535,5 +536,5 @@ def build_sharded_clustered_store(
         parts.append(np.asarray(cs.embeddings))
     return ShardedClusteredStore(
         shards=shards, shard_rows=rows,
-        embeddings=jnp.asarray(np.concatenate(parts)),
+        embeddings=np.concatenate(parts),
         perm=np.concatenate(perm))

@@ -1,59 +1,59 @@
-"""Fused cosine-distance probe kernels: counts-under-thresholds + block top-k.
+"""Fused cosine-distance probe kernel: counts-under-thresholds + block top-k.
 
 The Semantic Histogram's online hot path (paper §2.2 step 5): one pass over
-the (N, d) embedding store. Bandwidth-bound by design — both kernels stream
-N-blocks of the store HBM->VMEM and reduce counts + a per-block top-k in
+the (N, d) embedding store. Bandwidth-bound by design — the kernel streams
+N-blocks of the store HBM->VMEM and reduces counts + a per-block top-k in
 VMEM; distances never return to HBM.
 
-Two entry points:
+One ``pallas_call`` builder (``probe_blocks``) covers every probe the
+wrappers in ``ops.py`` expose, along two static axes:
 
-  * ``cosine_probe_blocks``        — one predicate: (block_n, d) x (d,)
-    broadcast-reduce on the VPU. The original scalar path.
-  * ``cosine_probe_batch_blocks``  — B predicates at once: one
-    (block_n, d) x (d, B) MXU matmul per store block. The store is streamed
-    HBM->VMEM **once** for the whole predicate batch, so probe HBM traffic
-    drops ~B× versus B scalar probes; arithmetic intensity rises from
-    ~1 FLOP/byte (matvec) to ~B FLOP/byte, moving the probe from the
-    bandwidth roof toward the MXU roof.
+  * ``scalar`` — one predicate scored by a VPU broadcast-reduce over d
+    (``(block_n, d) * (1, d)``), or B predicates scored by one MXU matmul
+    ``(block_n, d) x (d, B)`` per store block. The batched form streams the
+    store HBM->VMEM **once** for the whole predicate batch, so probe HBM
+    traffic drops ~B× versus B scalar probes.
+  * ``rows`` — which store rows are live:
+      ``"static"``  rows < ``n_total`` (a trace constant: tail padding);
+      ``"prefix"``  rows < a runtime SMEM scalar — the cluster-pruned index
+                    (``repro.index``) gathers boundary segments into a
+                    power-of-two bucket whose valid prefix changes every
+                    probe, so one compile serves every subset length;
+      ``"mask"``    a per-row int32 vector streamed with the store blocks —
+                    the mutable store's tombstones and hot-tail slots are
+                    live/dead in patterns a prefix cannot express.
+    Dead rows score +inf: never counted, never in the top-k.
 
-  * ``cosine_probe_batch_tiled_blocks`` — the same batched probe with a
-    second grid dimension over the predicate axis, for coalesced serving
-    batches with B >> 128 (cross-query micro-batching can hand the kernel
-    hundreds of predicates at once).
+Grid: (N / block_n, B_pad / block_b). The predicate axis is the *minor*
+grid dimension, so the (block_n, d) store block index is constant across
+the inner loop and Pallas fetches each store block from HBM once; only the
+small (d, block_b) panel restreams. ``block_b = B`` gives the untiled probe.
+Outputs are per-block partials merged by ops.py (O(nblocks * B * k)).
 
-  * ``cosine_probe_batch_masked_blocks`` — the batched probe with the valid
-    row count as a *dynamic* SMEM scalar instead of the static ``n_total``.
-    The cluster-pruned index (``repro.index``) gathers the union of
-    boundary-cluster segments into a power-of-two-padded buffer whose valid
-    prefix length changes every probe; baking that length in statically
-    would retrace per subset size, while the scalar-operand mask gives one
-    compile per padded bucket shape.
+Per-block top-k: the chip's Pallas lowering has no ``top_k`` or ``sort``,
+so the kernel selects the k smallest distances with k rounds of
+min-and-mask over the (B, block_n) tile (``_block_smallest``). Rounds cost
+a full tile pass each, so in-kernel selection is capped at
+``MAX_SELECT_K``; for larger k (threshold calibration) ops.py asks for the
+whole tile instead (``k == block_n``), which the kernel writes unselected
+and the XLA merge sorts.
 
-  * ``cosine_probe_rowmask_blocks`` / ``cosine_probe_batch_rowmask_blocks``
-    — the probe with a per-row *validity vector* instead of a prefix
-    length. The mutable store (``repro.index.mutable``) tombstones deleted
-    rows in place and appends inserts to a hot-tail buffer whose live rows
-    form an arbitrary 0/1 pattern, not a prefix; the mask streams alongside
-    the store blocks (a plain VMEM operand, one int32 lane per row), dead
-    rows score +inf, and the compile is still one trace per padded bucket
-    shape because the mask is data, not structure.
+Numerics: the MXU contraction runs at ``Precision.HIGHEST`` (f32 passes).
+At the default precision the chip rounds f32 operands to bf16, moving a
+cosine distance by ~1e-3 — far above the index's ``eps=1e-4`` bound slack,
+and enough to move a row across a threshold.
 
-Grid: (N / block_n,) for the untiled paths; (N / block_n, B / block_b) for
-the B-tiled path. Outputs are per-block partials merged by ops.py (the
-cross-block merge is O(nblocks * B * k) — negligible).
-
-TPU tiling / VMEM budget: block_n a multiple of 128 (lane dim), d padded to
-a multiple of 128 by ops.py. Scalar path per step: block_n*d*2B + block_n*4B
-(e.g. 2048 x 1152 bf16 = 4.7MB). Batched path adds the (d, B) predicate
-panel (1152 x 128 f32 = 0.6MB), the (block_n, B) distance tile
-(2048 x 128 f32 = 1MB) and (B, T) + (B, k) outputs — ~7MB at
-block_n=2048, d=1152, B=128, k=128, still inside v5e's 16MB VMEM with
-double buffering. For B >> 128 the panel would outgrow that budget, so the
-tiled path keeps a fixed (d, block_b) panel resident and walks predicate
-tiles in the *minor* grid dimension: the store block index is constant
-across the inner loop, so Pallas's pipelining fetches each store block from
-HBM once per outer step — store traffic stays N*d bytes total regardless of
-B, and VMEM per step is bounded by block_b, not B.
+TPU tiling / VMEM: block_n is a multiple of 128 (lanes), d is padded to a
+multiple of 128 by ops.py, and every output block's last two dims either
+equal the array's or are (8, 128)-aligned — hence the (nblocks, 1, T)
+scalar counts and the (1, N_pad) mask row. Pallas double-buffers the store
+block, so it holds 2 * block_n * d * itemsize bytes of VMEM (18.9 MB for an
+f32 2048 x 1152 block). The compiler's default *scoped* VMEM limit is
+16 MiB (v5e has 128 MiB of VMEM in all); ops.py picks block_n from d and
+the dtype so that the store buffers fit ``STORE_VMEM_BYTES`` under that
+default — 1024 rows at d=1152 f32. Raising the limit would buy nothing:
+a 4.7 MB block already takes ~6 µs to stream at 819 GB/s, far above the
+per-step overhead.
 """
 
 from __future__ import annotations
@@ -65,533 +65,137 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import interpret_mode
+
 f32 = jnp.float32
 
-
-def _probe_kernel(store_ref, pred_ref, thr_ref, counts_ref, topk_ref, *, k: int,
-                  block_n: int, n_total: int):
-    bi = pl.program_id(0)
-    block = store_ref[...].astype(f32)            # (block_n, d)
-    pred = pred_ref[...].astype(f32)              # (1, d)
-    sims = jnp.sum(block * pred, axis=-1)         # VPU reduce; MXU for wide d
-    dists = 1.0 - sims                            # (block_n,)
-
-    # mask tail padding rows with +inf distance
-    row = bi * block_n + jax.lax.broadcasted_iota(jnp.int32, (block_n,), 0)
-    dists = jnp.where(row < n_total, dists, jnp.inf)
-
-    thr = thr_ref[...]                            # (T,)
-    counts_ref[0, :] = jnp.sum(
-        (dists[None, :] <= thr[:, None]).astype(jnp.int32), axis=1
-    )
-    neg_top, _ = jax.lax.top_k(-dists, k)
-    topk_ref[0, :] = -neg_top
+MAX_SELECT_K = 128                 # largest k selected inside the kernel
+STORE_VMEM_BYTES = 10 * 1024 ** 2  # double-buffered store block budget
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("k", "block_n", "interpret", "n_total"))
-def cosine_probe_blocks(
-    store: jax.Array,          # (N_pad, d_pad) — padded by ops.py
-    pred: jax.Array,           # (1, d_pad)
-    thresholds: jax.Array,     # (T,)
-    *,
-    k: int,
-    n_total: int,
-    block_n: int = 2048,
-    interpret: bool = True,
-) -> tuple[jax.Array, jax.Array]:
-    n_pad, d = store.shape
-    t = thresholds.shape[0]
-    nblocks = n_pad // block_n
-    kernel = functools.partial(_probe_kernel, k=k, block_n=block_n,
-                               n_total=n_total)
-    counts, topk = pl.pallas_call(
-        kernel,
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((block_n, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, d), lambda i: (0, 0)),
-            pl.BlockSpec((t,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, t), lambda i: (i, 0)),
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nblocks, t), jnp.int32),
-            jax.ShapeDtypeStruct((nblocks, k), f32),
-        ],
-        interpret=interpret,
-    )(store, pred, thresholds)
-    return counts, topk
+def block_rows(d_pad: int, itemsize: int) -> int:
+    """Largest power-of-two store block (>= 128 rows, <= 2048) whose
+    double buffer fits ``STORE_VMEM_BYTES``."""
+    rows = 2048
+    while rows > 128 and 2 * rows * d_pad * itemsize > STORE_VMEM_BYTES:
+        rows //= 2
+    return rows
 
 
-def _probe_batch_kernel(store_ref, preds_ref, thr_ref, counts_ref, topk_ref, *,
-                        k: int, block_n: int, n_total: int):
-    bi = pl.program_id(0)
-    block = store_ref[...].astype(f32)            # (block_n, d)
-    preds = preds_ref[...].astype(f32)            # (d, B)
-    # the whole point: one MXU matmul scores the block against every predicate
-    sims = jnp.dot(block, preds, preferred_element_type=f32)  # (block_n, B)
-    dists = 1.0 - sims
+def _block_smallest(db: jax.Array, k: int) -> jax.Array:
+    """(R, L) -> (R, k): the k smallest per row, ascending.
 
-    # mask tail padding rows with +inf distance (broadcast over predicates)
-    row = bi * block_n + jax.lax.broadcasted_iota(jnp.int32, (block_n, 1), 0)
-    dists = jnp.where(row < n_total, dists, jnp.inf)
+    k rounds of min-and-mask: each round takes the row minimum and retires
+    its first occurrence (ties retire one lane per round, so duplicated
+    distances are kept with their multiplicity). ``k == L`` returns the
+    tile itself — the merge in ops.py sorts it."""
+    r, n = db.shape
+    if k == n:
+        return db
+    if k == 1:
+        return jnp.min(db, axis=1, keepdims=True)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (r, n), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (r, k), 1)
 
-    db = dists.T                                  # (B, block_n)
-    thr = thr_ref[...]                            # (B, T)
+    def one_round(i, carry):
+        d, out = carry
+        m = jnp.min(d, axis=1, keepdims=True)                     # (R, 1)
+        first = jnp.min(jnp.where(d == m, lane, n), axis=1, keepdims=True)
+        return (jnp.where(lane == first, jnp.inf, d),
+                jnp.where(col == i, m, out))
+
+    _, out = jax.lax.fori_loop(
+        0, k, one_round, (db, jnp.full((r, k), jnp.inf, f32)))
+    return out
+
+
+def _probe_kernel(*refs, k: int, block_n: int, rows: str, scalar: bool,
+                  n_total: int):
+    if rows == "prefix":
+        nv_ref, store_ref, preds_ref, thr_ref, counts_ref, topk_ref = refs
+    elif rows == "mask":
+        store_ref, mask_ref, preds_ref, thr_ref, counts_ref, topk_ref = refs
+    else:
+        store_ref, preds_ref, thr_ref, counts_ref, topk_ref = refs
+    block = store_ref[...].astype(f32)                    # (block_n, d)
+    if scalar:
+        # VPU broadcast-reduce: exact f32 products and sums
+        pred = preds_ref[...].astype(f32)                 # (1, d)
+        db = (1.0 - jnp.sum(block * pred, axis=-1))[None, :]   # (1, block_n)
+    else:
+        preds = preds_ref[...].astype(f32)                # (d, B)
+        sims = jnp.dot(block, preds, preferred_element_type=f32,
+                       precision=jax.lax.Precision.HIGHEST)   # (block_n, B)
+        db = (1.0 - sims).T                               # (B, block_n)
+
+    if rows == "mask":
+        live = mask_ref[...] != 0                         # (1, block_n)
+    else:
+        row = (pl.program_id(0) * block_n
+               + jax.lax.broadcasted_iota(jnp.int32, (1, block_n), 1))
+        live = row < (nv_ref[0, 0] if rows == "prefix" else n_total)
+    db = jnp.where(live, db, jnp.inf)
+
+    thr = thr_ref[...]                                    # (B, T)
     counts_ref[0] = jnp.sum(
-        (db[:, None, :] <= thr[:, :, None]).astype(jnp.int32), axis=-1
-    )                                             # (B, T)
-    neg_top, _ = jax.lax.top_k(-db, k)            # per-predicate block top-k
-    topk_ref[0] = -neg_top                        # (B, k)
+        (db[:, None, :] <= thr[:, :, None]).astype(jnp.int32), axis=-1)
+    topk_ref[0] = _block_smallest(db, k)                  # (B, k)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("k", "block_n", "interpret", "n_total"))
-def cosine_probe_batch_blocks(
+@functools.partial(jax.jit, static_argnames=(
+    "k", "block_n", "block_b", "rows", "scalar", "n_total", "interpret"))
+def probe_blocks(
     store: jax.Array,          # (N_pad, d_pad) — padded by ops.py
-    preds: jax.Array,          # (d_pad, B) — predicate panel, column-major
-    thresholds: jax.Array,     # (B, T) per-predicate threshold vectors
-    *,
-    k: int,
-    n_total: int,
-    block_n: int = 2048,
-    interpret: bool = True,
-) -> tuple[jax.Array, jax.Array]:
-    n_pad, d = store.shape
-    b = preds.shape[1]
-    t = thresholds.shape[1]
-    nblocks = n_pad // block_n
-    kernel = functools.partial(_probe_batch_kernel, k=k, block_n=block_n,
-                               n_total=n_total)
-    counts, topk = pl.pallas_call(
-        kernel,
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((block_n, d), lambda i: (i, 0)),
-            pl.BlockSpec((d, b), lambda i: (0, 0)),
-            pl.BlockSpec((b, t), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, b, t), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, b, k), lambda i: (i, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nblocks, b, t), jnp.int32),
-            jax.ShapeDtypeStruct((nblocks, b, k), f32),
-        ],
-        interpret=interpret,
-    )(store, preds, thresholds)
-    return counts, topk
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("k", "block_n", "block_b", "interpret",
-                                    "n_total"))
-def cosine_probe_batch_tiled_blocks(
-    store: jax.Array,          # (N_pad, d_pad) — padded by ops.py
-    preds: jax.Array,          # (d_pad, B_pad) — B padded to block_b by ops.py
+    preds: jax.Array,          # (1, d_pad) scalar | (d_pad, B_pad) panel
     thresholds: jax.Array,     # (B_pad, T) per-predicate threshold vectors
+    valid: jax.Array | None = None,  # (1, 1) int32 n_valid | (1, N_pad) mask
     *,
     k: int,
-    n_total: int,
-    block_n: int = 2048,
-    block_b: int = 128,
-    interpret: bool = True,
+    block_n: int,
+    block_b: int | None = None,
+    rows: str = "static",      # static | prefix | mask
+    scalar: bool = False,
+    n_total: int = 0,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """B-tiled batched probe: grid (nblocks, B_pad/block_b).
-
-    Reuses ``_probe_batch_kernel`` unchanged — the body only consults
-    ``program_id(0)`` (store-block index, for tail masking); the predicate
-    tile offset is entirely in the BlockSpec index maps. The predicate axis
-    is the minor grid dimension so the (block_n, d) store block stays
-    resident across all predicate tiles (one HBM fetch per store block);
-    only the small (d, block_b) panel and (block_b, T) thresholds restream.
-    """
+    """Per-block partials: (counts (nblocks, B_pad, T) int32, smallest
+    (nblocks, B_pad, k) f32). ``k`` is at most ``MAX_SELECT_K`` or equal to
+    ``block_n`` (the whole tile, unselected)."""
+    if not (k <= MAX_SELECT_K or k == block_n):
+        raise ValueError(f"in-kernel top-k takes k <= {MAX_SELECT_K} or "
+                         f"k == block_n ({block_n}), got k={k}")
     n_pad, d = store.shape
-    b_pad = preds.shape[1]
-    t = thresholds.shape[1]
-    nblocks = n_pad // block_n
-    nbt = b_pad // block_b
-    kernel = functools.partial(_probe_batch_kernel, k=k, block_n=block_n,
-                               n_total=n_total)
-    counts, topk = pl.pallas_call(
+    b_pad, t = thresholds.shape
+    bb = b_pad if block_b is None else block_b
+    nblocks, nbt = n_pad // block_n, b_pad // bb
+    kernel = functools.partial(_probe_kernel, k=k, block_n=block_n,
+                               rows=rows, scalar=scalar, n_total=n_total)
+    in_specs = [pl.BlockSpec((block_n, d), lambda i, j: (i, 0))]
+    operands = [store]
+    if rows == "prefix":
+        in_specs.insert(0, pl.BlockSpec((1, 1), lambda i, j: (0, 0),
+                                        memory_space=pltpu.SMEM))
+        operands.insert(0, valid)
+    elif rows == "mask":
+        in_specs.append(pl.BlockSpec((1, block_n), lambda i, j: (0, i)))
+        operands.append(valid)
+    if scalar:
+        in_specs.append(pl.BlockSpec((1, d), lambda i, j: (0, 0)))
+    else:
+        in_specs.append(pl.BlockSpec((d, bb), lambda i, j: (0, j)))
+    in_specs.append(pl.BlockSpec((bb, t), lambda i, j: (j, 0)))
+    operands += [preds, thresholds]
+    return pl.pallas_call(
         kernel,
         grid=(nblocks, nbt),
-        in_specs=[
-            pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((d, block_b), lambda i, j: (0, j)),
-            pl.BlockSpec((block_b, t), lambda i, j: (j, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_b, t), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_b, k), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, bb, t), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, bb, k), lambda i, j: (i, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((nblocks, b_pad, t), jnp.int32),
             jax.ShapeDtypeStruct((nblocks, b_pad, k), f32),
         ],
-        interpret=interpret,
-    )(store, preds, thresholds)
-    return counts, topk
-
-
-def _probe_masked_kernel(nv_ref, store_ref, pred_ref, thr_ref, counts_ref,
-                         topk_ref, *, k: int, block_n: int):
-    """Scalar twin of ``_probe_batch_masked_kernel`` — same VPU
-    broadcast-reduce as ``_probe_kernel`` so a pruned one-predicate scan is
-    bitwise the full scalar scan (the MXU batch matmul reduces in a
-    different order and can differ in the last ulp)."""
-    bi = pl.program_id(0)
-    block = store_ref[...].astype(f32)            # (block_n, d)
-    pred = pred_ref[...].astype(f32)              # (1, d)
-    sims = jnp.sum(block * pred, axis=-1)
-    dists = 1.0 - sims                            # (block_n,)
-
-    row = bi * block_n + jax.lax.broadcasted_iota(jnp.int32, (block_n,), 0)
-    dists = jnp.where(row < nv_ref[0, 0], dists, jnp.inf)
-
-    thr = thr_ref[...]                            # (T,)
-    counts_ref[0, :] = jnp.sum(
-        (dists[None, :] <= thr[:, None]).astype(jnp.int32), axis=1
-    )
-    neg_top, _ = jax.lax.top_k(-dists, k)
-    topk_ref[0, :] = -neg_top
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("k", "block_n", "interpret"))
-def cosine_probe_masked_blocks(
-    store: jax.Array,          # (N_pad, d_pad) — padded by ops.py
-    n_valid: jax.Array,        # (1, 1) int32 — rows < n_valid are live
-    pred: jax.Array,           # (1, d_pad)
-    thresholds: jax.Array,     # (T,)
-    *,
-    k: int,
-    block_n: int = 2048,
-    interpret: bool = True,
-) -> tuple[jax.Array, jax.Array]:
-    n_pad, d = store.shape
-    t = thresholds.shape[0]
-    nblocks = n_pad // block_n
-    kernel = functools.partial(_probe_masked_kernel, k=k, block_n=block_n)
-    counts, topk = pl.pallas_call(
-        kernel,
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((block_n, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, d), lambda i: (0, 0)),
-            pl.BlockSpec((t,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, t), lambda i: (i, 0)),
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nblocks, t), jnp.int32),
-            jax.ShapeDtypeStruct((nblocks, k), f32),
-        ],
-        interpret=interpret,
-    )(n_valid, store, pred, thresholds)
-    return counts, topk
-
-
-def _probe_batch_masked_kernel(nv_ref, store_ref, preds_ref, thr_ref,
-                               counts_ref, topk_ref, *, k: int, block_n: int):
-    bi = pl.program_id(0)
-    block = store_ref[...].astype(f32)            # (block_n, d)
-    preds = preds_ref[...].astype(f32)            # (d, B)
-    sims = jnp.dot(block, preds, preferred_element_type=f32)  # (block_n, B)
-    dists = 1.0 - sims
-
-    # mask rows past the *runtime* valid count with +inf distance — the
-    # valid prefix length varies per probe (pruned boundary subsets), so it
-    # arrives as an SMEM scalar rather than a static trace constant
-    row = bi * block_n + jax.lax.broadcasted_iota(jnp.int32, (block_n, 1), 0)
-    dists = jnp.where(row < nv_ref[0, 0], dists, jnp.inf)
-
-    db = dists.T                                  # (B, block_n)
-    thr = thr_ref[...]                            # (B, T)
-    counts_ref[0] = jnp.sum(
-        (db[:, None, :] <= thr[:, :, None]).astype(jnp.int32), axis=-1
-    )                                             # (B, T)
-    neg_top, _ = jax.lax.top_k(-db, k)
-    topk_ref[0] = -neg_top                        # (B, k)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("k", "block_n", "interpret"))
-def cosine_probe_batch_masked_blocks(
-    store: jax.Array,          # (N_pad, d_pad) — padded by ops.py
-    n_valid: jax.Array,        # (1, 1) int32 — rows < n_valid are live
-    preds: jax.Array,          # (d_pad, B) — predicate panel, column-major
-    thresholds: jax.Array,     # (B, T) per-predicate threshold vectors
-    *,
-    k: int,
-    block_n: int = 2048,
-    interpret: bool = True,
-) -> tuple[jax.Array, jax.Array]:
-    """Batched probe over a dynamically-masked row prefix.
-
-    Identical math to ``cosine_probe_batch_blocks`` but the tail mask reads
-    ``n_valid`` from SMEM at run time: one trace serves every subset length
-    that pads to the same bucket shape. Used by the cluster-pruned index,
-    whose boundary-union scan buffer changes length on every probe.
-    """
-    n_pad, d = store.shape
-    b = preds.shape[1]
-    t = thresholds.shape[1]
-    nblocks = n_pad // block_n
-    kernel = functools.partial(_probe_batch_masked_kernel, k=k,
-                               block_n=block_n)
-    counts, topk = pl.pallas_call(
-        kernel,
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((block_n, d), lambda i: (i, 0)),
-            pl.BlockSpec((d, b), lambda i: (0, 0)),
-            pl.BlockSpec((b, t), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, b, t), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, b, k), lambda i: (i, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nblocks, b, t), jnp.int32),
-            jax.ShapeDtypeStruct((nblocks, b, k), f32),
-        ],
-        interpret=interpret,
-    )(n_valid, store, preds, thresholds)
-    return counts, topk
-
-
-def _probe_rowmask_kernel(store_ref, mask_ref, pred_ref, thr_ref, counts_ref,
-                          topk_ref, *, k: int):
-    """Scalar probe with a per-row live mask — same VPU broadcast-reduce as
-    ``_probe_kernel`` so a tombstone-masked scan's per-row distances are
-    bitwise the full scalar scan's (the reduce is over d, row-local; which
-    rows are masked cannot change any live row's value)."""
-    block = store_ref[...].astype(f32)            # (block_n, d)
-    pred = pred_ref[...].astype(f32)              # (1, d)
-    sims = jnp.sum(block * pred, axis=-1)
-    dists = 1.0 - sims                            # (block_n,)
-
-    # dead rows (tombstones + bucket padding) carry mask 0 -> +inf distance
-    dists = jnp.where(mask_ref[...] != 0, dists, jnp.inf)
-
-    thr = thr_ref[...]                            # (T,)
-    counts_ref[0, :] = jnp.sum(
-        (dists[None, :] <= thr[:, None]).astype(jnp.int32), axis=1
-    )
-    neg_top, _ = jax.lax.top_k(-dists, k)
-    topk_ref[0, :] = -neg_top
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("k", "block_n", "interpret"))
-def cosine_probe_rowmask_blocks(
-    store: jax.Array,          # (N_pad, d_pad) — padded by ops.py
-    mask: jax.Array,           # (N_pad,) int32 — 0 = dead row / padding
-    pred: jax.Array,           # (1, d_pad)
-    thresholds: jax.Array,     # (T,)
-    *,
-    k: int,
-    block_n: int = 2048,
-    interpret: bool = True,
-) -> tuple[jax.Array, jax.Array]:
-    n_pad, d = store.shape
-    t = thresholds.shape[0]
-    nblocks = n_pad // block_n
-    kernel = functools.partial(_probe_rowmask_kernel, k=k)
-    counts, topk = pl.pallas_call(
-        kernel,
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((block_n, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((1, d), lambda i: (0, 0)),
-            pl.BlockSpec((t,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, t), lambda i: (i, 0)),
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nblocks, t), jnp.int32),
-            jax.ShapeDtypeStruct((nblocks, k), f32),
-        ],
-        interpret=interpret,
-    )(store, mask, pred, thresholds)
-    return counts, topk
-
-
-def _probe_batch_rowmask_kernel(store_ref, mask_ref, preds_ref, thr_ref,
-                                counts_ref, topk_ref, *, k: int):
-    """Batched twin of ``_probe_rowmask_kernel`` — MXU matmul like
-    ``_probe_batch_kernel``, per-row mask broadcast over predicates."""
-    block = store_ref[...].astype(f32)            # (block_n, d)
-    preds = preds_ref[...].astype(f32)            # (d, B)
-    sims = jnp.dot(block, preds, preferred_element_type=f32)  # (block_n, B)
-    dists = 1.0 - sims
-
-    dists = jnp.where(mask_ref[...][:, None] != 0, dists, jnp.inf)
-
-    db = dists.T                                  # (B, block_n)
-    thr = thr_ref[...]                            # (B, T)
-    counts_ref[0] = jnp.sum(
-        (db[:, None, :] <= thr[:, :, None]).astype(jnp.int32), axis=-1
-    )                                             # (B, T)
-    neg_top, _ = jax.lax.top_k(-db, k)
-    topk_ref[0] = -neg_top                        # (B, k)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("k", "block_n", "interpret"))
-def cosine_probe_batch_rowmask_blocks(
-    store: jax.Array,          # (N_pad, d_pad) — padded by ops.py
-    mask: jax.Array,           # (N_pad,) int32 — 0 = dead row / padding
-    preds: jax.Array,          # (d_pad, B) — predicate panel, column-major
-    thresholds: jax.Array,     # (B, T) per-predicate threshold vectors
-    *,
-    k: int,
-    block_n: int = 2048,
-    interpret: bool = True,
-) -> tuple[jax.Array, jax.Array]:
-    """Batched probe over an arbitrarily-masked row set.
-
-    Identical math to ``cosine_probe_batch_blocks`` but validity comes from
-    a per-row mask vector streamed with the store blocks: the mutable
-    store's hot tail and tombstoned segments are live/dead in arbitrary
-    patterns a prefix length cannot express. One trace per padded bucket
-    shape — the mask is a data operand.
-    """
-    n_pad, d = store.shape
-    b = preds.shape[1]
-    t = thresholds.shape[1]
-    nblocks = n_pad // block_n
-    kernel = functools.partial(_probe_batch_rowmask_kernel, k=k)
-    counts, topk = pl.pallas_call(
-        kernel,
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((block_n, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((d, b), lambda i: (0, 0)),
-            pl.BlockSpec((b, t), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, b, t), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, b, k), lambda i: (i, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nblocks, b, t), jnp.int32),
-            jax.ShapeDtypeStruct((nblocks, b, k), f32),
-        ],
-        interpret=interpret,
-    )(store, mask, preds, thresholds)
-    return counts, topk
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("k", "block_n", "block_b", "interpret"))
-def cosine_probe_batch_rowmask_tiled_blocks(
-    store: jax.Array,          # (N_pad, d_pad) — padded by ops.py
-    mask: jax.Array,           # (N_pad,) int32 — 0 = dead row / padding
-    preds: jax.Array,          # (d_pad, B_pad) — B padded to block_b by ops.py
-    thresholds: jax.Array,     # (B_pad, T)
-    *,
-    k: int,
-    block_n: int = 2048,
-    block_b: int = 128,
-    interpret: bool = True,
-) -> tuple[jax.Array, jax.Array]:
-    """B-tiled rowmask probe: grid (nblocks, B_pad/block_b).
-
-    Same composition as the other tiled paths — the rowmask kernel body
-    reads no ``program_id`` at all (validity is entirely in the mask
-    operand), so the predicate-tile offset lives in the BlockSpec index
-    maps and VMEM per step stays bounded by ``block_b``.
-    """
-    n_pad, d = store.shape
-    b_pad = preds.shape[1]
-    t = thresholds.shape[1]
-    nblocks = n_pad // block_n
-    nbt = b_pad // block_b
-    kernel = functools.partial(_probe_batch_rowmask_kernel, k=k)
-    counts, topk = pl.pallas_call(
-        kernel,
-        grid=(nblocks, nbt),
-        in_specs=[
-            pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),
-            pl.BlockSpec((d, block_b), lambda i, j: (0, j)),
-            pl.BlockSpec((block_b, t), lambda i, j: (j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_b, t), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_b, k), lambda i, j: (i, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nblocks, b_pad, t), jnp.int32),
-            jax.ShapeDtypeStruct((nblocks, b_pad, k), f32),
-        ],
-        interpret=interpret,
-    )(store, mask, preds, thresholds)
-    return counts, topk
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("k", "block_n", "block_b", "interpret"))
-def cosine_probe_batch_masked_tiled_blocks(
-    store: jax.Array,          # (N_pad, d_pad) — padded by ops.py
-    n_valid: jax.Array,        # (1, 1) int32 — rows < n_valid are live
-    preds: jax.Array,          # (d_pad, B_pad) — B padded to block_b by ops.py
-    thresholds: jax.Array,     # (B_pad, T)
-    *,
-    k: int,
-    block_n: int = 2048,
-    block_b: int = 128,
-    interpret: bool = True,
-) -> tuple[jax.Array, jax.Array]:
-    """B-tiled masked probe: grid (nblocks, B_pad/block_b).
-
-    Same composition as ``cosine_probe_batch_tiled_blocks`` — the masked
-    kernel body only consults ``program_id(0)`` (row masking), so the
-    predicate-tile offset lives entirely in the BlockSpec index maps and
-    VMEM per step stays bounded by ``block_b`` for the coalesced pruned
-    batches with B >> 128.
-    """
-    n_pad, d = store.shape
-    b_pad = preds.shape[1]
-    t = thresholds.shape[1]
-    nblocks = n_pad // block_n
-    nbt = b_pad // block_b
-    kernel = functools.partial(_probe_batch_masked_kernel, k=k,
-                               block_n=block_n)
-    counts, topk = pl.pallas_call(
-        kernel,
-        grid=(nblocks, nbt),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((d, block_b), lambda i, j: (0, j)),
-            pl.BlockSpec((block_b, t), lambda i, j: (j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_b, t), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_b, k), lambda i, j: (i, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nblocks, b_pad, t), jnp.int32),
-            jax.ShapeDtypeStruct((nblocks, b_pad, k), f32),
-        ],
-        interpret=interpret,
-    )(n_valid, store, preds, thresholds)
-    return counts, topk
+        interpret=interpret_mode(interpret),
+    )(*operands)

@@ -25,6 +25,11 @@ subset length changes every probe but the padded bucket shape does not.
 +inf) — the entry points for the mutable store's hot-tail and tombstone
 scans, where live rows are not a prefix. The mask is padded with zeros to
 the same bucket as the store, so padding never scores.
+
+Shape rules: ``block_n=None`` picks the store block from d and the dtype
+(``kernel.block_rows``). The kernel selects each block's top-k in VMEM for
+k <= ``kernel.MAX_SELECT_K``; a larger k (threshold calibration) makes it
+write every block's whole distance tile, and the merge below sorts them.
 """
 
 from __future__ import annotations
@@ -35,15 +40,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.cosine_topk.kernel import (
-    cosine_probe_batch_blocks,
-    cosine_probe_batch_masked_blocks,
-    cosine_probe_batch_masked_tiled_blocks,
-    cosine_probe_batch_rowmask_blocks,
-    cosine_probe_batch_rowmask_tiled_blocks,
-    cosine_probe_batch_tiled_blocks,
-    cosine_probe_blocks,
-    cosine_probe_masked_blocks,
-    cosine_probe_rowmask_blocks,
+    MAX_SELECT_K,
+    block_rows,
+    probe_blocks,
 )
 
 f32 = jnp.float32
@@ -58,44 +57,75 @@ def _pad_to(x, m, axis, value=0.0):
     return jnp.pad(x, widths, constant_values=value)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret"))
+def _probe(store, preds, thresholds, valid, *, k, block_n, block_b, tiled,
+           rows, scalar, interpret):
+    """Shared body: preds (B, d), thresholds (B, T) -> (counts (B, T),
+    k smallest distances (B, k) ascending)."""
+    n, d = store.shape
+    b = preds.shape[0]
+    k = min(k, n)
+    if block_n is None:
+        block_n = block_rows(-(-d // 128) * 128, store.dtype.itemsize)
+    block_n = min(block_n, max(128, 1 << (n - 1).bit_length()))
+    kk = min(max(k, 1), block_n)
+    if kk > MAX_SELECT_K:
+        kk = block_n                  # whole tiles; the merge sorts them
+    sp = _pad_to(_pad_to(store, 128, 1), block_n, 0)
+    thr = thresholds.astype(f32)
+    preds = preds.astype(store.dtype)
+    if rows == "prefix":
+        valid = jnp.asarray(valid, jnp.int32).reshape(1, 1)
+    elif rows == "mask":
+        valid = _pad_to(valid.astype(jnp.int32), block_n, 0)[None, :]
+    bb = None
+    if not scalar and (b > block_b if tiled is None else tiled):
+        # pad the predicate axis to a tile multiple; zero columns are
+        # scored but sliced off below, so padding never changes results
+        bb = min(block_b, max(8, 1 << (b - 1).bit_length()))
+        preds, thr = _pad_to(preds, bb, 0), _pad_to(thr, bb, 0)
+    pp = _pad_to(preds, 128, 1)
+    counts_b, topk_b = probe_blocks(
+        sp, pp if scalar else pp.T, thr, valid, k=kk, block_n=block_n,
+        block_b=bb, rows=rows, scalar=scalar, n_total=n,
+        interpret=interpret)
+    counts = counts_b[:, :b].sum(axis=0)                    # (B, T)
+    # (nblocks, B, kk) -> (B, nblocks*kk) -> per-predicate global top-k
+    flat = topk_b[:, :b].transpose(1, 0, 2).reshape(b, -1)
+    return counts, -jax.lax.top_k(-flat, k)[0]
+
+
+_STATIC = ("k", "block_n", "interpret")
+_STATIC_BATCH = ("k", "block_n", "block_b", "tiled", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def cosine_probe(
     store: jax.Array,        # (N, d)
     pred: jax.Array,         # (d,)
     thresholds: jax.Array,   # (T,)
     *,
     k: int = 128,
-    block_n: int = 2048,
-    interpret: bool = True,  # CPU container; False on real TPU
+    block_n: int | None = None,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Fused probe: (counts (T,) int32, k smallest distances (k,) ascending)."""
-    n = store.shape[0]
-    k = min(k, n)
-    block_n = min(block_n, max(128, 1 << (n - 1).bit_length()))
-    sp = _pad_to(_pad_to(store, 128, 1), block_n, 0)
-    pp = _pad_to(pred[None, :].astype(store.dtype), 128, 1)
-    kk = min(max(k, 1), block_n)
-    counts_b, topk_b = cosine_probe_blocks(
-        sp, pp, thresholds.astype(f32), k=kk, n_total=n, block_n=block_n,
-        interpret=interpret,
-    )
-    counts = counts_b.sum(axis=0)
-    merged = -jax.lax.top_k(-topk_b.reshape(-1), k)[0]
-    return counts, merged
+    counts, top = _probe(store, pred[None, :], thresholds[None, :], None,
+                         k=k, block_n=block_n, block_b=None, tiled=False,
+                         rows="static", scalar=True, interpret=interpret)
+    return counts[0], top[0]
 
 
-@functools.partial(jax.jit, static_argnames=("k", "block_n", "block_b",
-                                             "tiled", "interpret"))
+@functools.partial(jax.jit, static_argnames=_STATIC_BATCH)
 def cosine_probe_batch(
     store: jax.Array,        # (N, d)
     preds: jax.Array,        # (B, d) predicate batch
     thresholds: jax.Array,   # (B, T) per-predicate threshold vectors
     *,
     k: int = 128,
-    block_n: int = 2048,
+    block_n: int | None = None,
     block_b: int = 128,
     tiled: bool | None = None,  # None = auto (tile when B > block_b)
-    interpret: bool = True,  # CPU container; False on real TPU
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Batched fused probe — one store pass for B predicates.
 
@@ -105,42 +135,12 @@ def cosine_probe_batch(
 
     Returns (counts (B, T) int32, k smallest distances (B, k) ascending).
     """
-    n = store.shape[0]
-    b = preds.shape[0]
-    k = min(k, n)
-    block_n = min(block_n, max(128, 1 << (n - 1).bit_length()))
-    sp = _pad_to(_pad_to(store, 128, 1), block_n, 0)
-    kk = min(max(k, 1), block_n)
-    thr = thresholds.astype(f32)
-    if tiled is None:
-        tiled = b > block_b
-    if tiled:
-        # pad the predicate axis to a block_b multiple; zero columns are
-        # scored but sliced off below, so padding never changes results
-        bb = min(block_b, max(8, 1 << (b - 1).bit_length()))
-        preds_p = _pad_to(preds.astype(store.dtype), bb, 0)
-        pp = _pad_to(preds_p, 128, 1).T                    # (d_pad, B_pad)
-        thr_p = _pad_to(thr, bb, 0)
-        counts_b, topk_b = cosine_probe_batch_tiled_blocks(
-            sp, pp, thr_p, k=kk, n_total=n, block_n=block_n, block_b=bb,
-            interpret=interpret,
-        )
-        counts_b = counts_b[:, :b]
-        topk_b = topk_b[:, :b]
-    else:
-        pp = _pad_to(preds.astype(store.dtype), 128, 1).T  # (d_pad, B)
-        counts_b, topk_b = cosine_probe_batch_blocks(
-            sp, pp, thr, k=kk, n_total=n, block_n=block_n,
-            interpret=interpret,
-        )
-    counts = counts_b.sum(axis=0)                          # (B, T)
-    # (nblocks, B, kk) -> (B, nblocks*kk) -> per-predicate global top-k
-    flat = topk_b.transpose(1, 0, 2).reshape(b, -1)
-    merged = -jax.lax.top_k(-flat, k)[0]
-    return counts, merged
+    return _probe(store, preds, thresholds, None, k=k, block_n=block_n,
+                  block_b=block_b, tiled=tiled, rows="static", scalar=False,
+                  interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret"))
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def cosine_probe_masked(
     store: jax.Array,        # (M, d) scan buffer; rows >= n_valid are dead
     n_valid: jax.Array,      # int32 scalar — live row-prefix length
@@ -148,8 +148,8 @@ def cosine_probe_masked(
     thresholds: jax.Array,   # (T,)
     *,
     k: int = 128,
-    block_n: int = 2048,
-    interpret: bool = True,  # CPU container; False on real TPU
+    block_n: int | None = None,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Scalar probe over the first ``n_valid`` rows of ``store``.
 
@@ -157,24 +157,13 @@ def cosine_probe_masked(
     kernel's VPU reduce, so a pruned scan's distances are bitwise the full
     ``cosine_probe`` scan's. Returns (counts (T,), top-k (k,) ascending).
     """
-    m = store.shape[0]
-    k = min(k, m)
-    block_n = min(block_n, max(128, 1 << (m - 1).bit_length()))
-    sp = _pad_to(_pad_to(store, 128, 1), block_n, 0)
-    pp = _pad_to(pred[None, :].astype(store.dtype), 128, 1)
-    nv = jnp.asarray(n_valid, jnp.int32).reshape(1, 1)
-    kk = min(max(k, 1), block_n)
-    counts_b, topk_b = cosine_probe_masked_blocks(
-        sp, nv, pp, thresholds.astype(f32), k=kk, block_n=block_n,
-        interpret=interpret,
-    )
-    counts = counts_b.sum(axis=0)
-    merged = -jax.lax.top_k(-topk_b.reshape(-1), k)[0]
-    return counts, merged
+    counts, top = _probe(store, pred[None, :], thresholds[None, :], n_valid,
+                         k=k, block_n=block_n, block_b=None, tiled=False,
+                         rows="prefix", scalar=True, interpret=interpret)
+    return counts[0], top[0]
 
 
-@functools.partial(jax.jit, static_argnames=("k", "block_n", "block_b",
-                                             "tiled", "interpret"))
+@functools.partial(jax.jit, static_argnames=_STATIC_BATCH)
 def cosine_probe_batch_masked(
     store: jax.Array,        # (M, d) scan buffer; rows >= n_valid are dead
     n_valid: jax.Array,      # int32 scalar — live row-prefix length
@@ -182,10 +171,10 @@ def cosine_probe_batch_masked(
     thresholds: jax.Array,   # (B, T) per-predicate threshold vectors
     *,
     k: int = 128,
-    block_n: int = 2048,
+    block_n: int | None = None,
     block_b: int = 128,
     tiled: bool | None = None,  # None = auto (tile when B > block_b)
-    interpret: bool = True,  # CPU container; False on real TPU
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Batched probe over the first ``n_valid`` rows of ``store``.
 
@@ -202,38 +191,12 @@ def cosine_probe_batch_masked(
 
     Returns (counts (B, T) int32, k smallest distances (B, k) ascending).
     """
-    m = store.shape[0]
-    b = preds.shape[0]
-    k = min(k, m)
-    block_n = min(block_n, max(128, 1 << (m - 1).bit_length()))
-    sp = _pad_to(_pad_to(store, 128, 1), block_n, 0)
-    nv = jnp.asarray(n_valid, jnp.int32).reshape(1, 1)
-    kk = min(max(k, 1), block_n)
-    thr = thresholds.astype(f32)
-    if tiled is None:
-        tiled = b > block_b
-    if tiled:
-        bb = min(block_b, max(8, 1 << (b - 1).bit_length()))
-        preds_p = _pad_to(preds.astype(store.dtype), bb, 0)
-        pp = _pad_to(preds_p, 128, 1).T                     # (d_pad, B_pad)
-        counts_b, topk_b = cosine_probe_batch_masked_tiled_blocks(
-            sp, nv, pp, _pad_to(thr, bb, 0), k=kk, block_n=block_n,
-            block_b=bb, interpret=interpret,
-        )
-        counts_b = counts_b[:, :b]
-        topk_b = topk_b[:, :b]
-    else:
-        pp = _pad_to(preds.astype(store.dtype), 128, 1).T   # (d_pad, B)
-        counts_b, topk_b = cosine_probe_batch_masked_blocks(
-            sp, nv, pp, thr, k=kk, block_n=block_n, interpret=interpret,
-        )
-    counts = counts_b.sum(axis=0)                           # (B, T)
-    flat = topk_b.transpose(1, 0, 2).reshape(b, -1)
-    merged = -jax.lax.top_k(-flat, k)[0]
-    return counts, merged
+    return _probe(store, preds, thresholds, n_valid, k=k, block_n=block_n,
+                  block_b=block_b, tiled=tiled, rows="prefix", scalar=False,
+                  interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret"))
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def cosine_probe_rowmask(
     store: jax.Array,        # (M, d) scan buffer
     mask: jax.Array,         # (M,) — nonzero = live row; 0 = tombstone
@@ -241,8 +204,8 @@ def cosine_probe_rowmask(
     thresholds: jax.Array,   # (T,)
     *,
     k: int = 128,
-    block_n: int = 2048,
-    interpret: bool = True,  # CPU container; False on real TPU
+    block_n: int | None = None,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Scalar probe over the live (mask != 0) rows of ``store``.
 
@@ -251,24 +214,13 @@ def cosine_probe_rowmask(
     a masked scan's per-row distances are bitwise the full scalar scan's.
     Returns (counts (T,), top-k (k,) ascending; dead slots come back +inf).
     """
-    m = store.shape[0]
-    k = min(k, m)
-    block_n = min(block_n, max(128, 1 << (m - 1).bit_length()))
-    sp = _pad_to(_pad_to(store, 128, 1), block_n, 0)
-    mp = _pad_to(mask.astype(jnp.int32), block_n, 0)   # padding rows dead
-    pp = _pad_to(pred[None, :].astype(store.dtype), 128, 1)
-    kk = min(max(k, 1), block_n)
-    counts_b, topk_b = cosine_probe_rowmask_blocks(
-        sp, mp, pp, thresholds.astype(f32), k=kk, block_n=block_n,
-        interpret=interpret,
-    )
-    counts = counts_b.sum(axis=0)
-    merged = -jax.lax.top_k(-topk_b.reshape(-1), k)[0]
-    return counts, merged
+    counts, top = _probe(store, pred[None, :], thresholds[None, :], mask,
+                         k=k, block_n=block_n, block_b=None, tiled=False,
+                         rows="mask", scalar=True, interpret=interpret)
+    return counts[0], top[0]
 
 
-@functools.partial(jax.jit, static_argnames=("k", "block_n", "block_b",
-                                             "tiled", "interpret"))
+@functools.partial(jax.jit, static_argnames=_STATIC_BATCH)
 def cosine_probe_batch_rowmask(
     store: jax.Array,        # (M, d) scan buffer
     mask: jax.Array,         # (M,) — nonzero = live row; 0 = tombstone
@@ -276,10 +228,10 @@ def cosine_probe_batch_rowmask(
     thresholds: jax.Array,   # (B, T) per-predicate threshold vectors
     *,
     k: int = 128,
-    block_n: int = 2048,
+    block_n: int | None = None,
     block_b: int = 128,
     tiled: bool | None = None,  # None = auto (tile when B > block_b)
-    interpret: bool = True,  # CPU container; False on real TPU
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Batched probe over the live (mask != 0) rows of ``store``.
 
@@ -291,32 +243,6 @@ def cosine_probe_batch_rowmask(
 
     Returns (counts (B, T) int32, k smallest distances (B, k) ascending).
     """
-    m = store.shape[0]
-    b = preds.shape[0]
-    k = min(k, m)
-    block_n = min(block_n, max(128, 1 << (m - 1).bit_length()))
-    sp = _pad_to(_pad_to(store, 128, 1), block_n, 0)
-    mp = _pad_to(mask.astype(jnp.int32), block_n, 0)
-    kk = min(max(k, 1), block_n)
-    thr = thresholds.astype(f32)
-    if tiled is None:
-        tiled = b > block_b
-    if tiled:
-        bb = min(block_b, max(8, 1 << (b - 1).bit_length()))
-        preds_p = _pad_to(preds.astype(store.dtype), bb, 0)
-        pp = _pad_to(preds_p, 128, 1).T                     # (d_pad, B_pad)
-        counts_b, topk_b = cosine_probe_batch_rowmask_tiled_blocks(
-            sp, mp, pp, _pad_to(thr, bb, 0), k=kk, block_n=block_n,
-            block_b=bb, interpret=interpret,
-        )
-        counts_b = counts_b[:, :b]
-        topk_b = topk_b[:, :b]
-    else:
-        pp = _pad_to(preds.astype(store.dtype), 128, 1).T   # (d_pad, B)
-        counts_b, topk_b = cosine_probe_batch_rowmask_blocks(
-            sp, mp, pp, thr, k=kk, block_n=block_n, interpret=interpret,
-        )
-    counts = counts_b.sum(axis=0)                           # (B, T)
-    flat = topk_b.transpose(1, 0, 2).reshape(b, -1)
-    merged = -jax.lax.top_k(-flat, k)[0]
-    return counts, merged
+    return _probe(store, preds, thresholds, mask, k=k, block_n=block_n,
+                  block_b=block_b, tiled=tiled, rows="mask", scalar=False,
+                  interpret=interpret)

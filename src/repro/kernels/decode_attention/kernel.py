@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import interpret_mode
+
 f32 = jnp.float32
 NEG_INF = -1e30
 
@@ -67,7 +69,7 @@ def decode_fwd(
     *,
     scale: float,
     kc: int = 1024,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     B, Hkv, rep, D = q.shape
     nk = k.shape[2] // kc
@@ -89,5 +91,5 @@ def decode_fwd(
             pltpu.VMEM((rep,), f32),
             pltpu.VMEM((rep, D), f32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(kv_valid, q, k, v)

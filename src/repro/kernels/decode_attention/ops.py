@@ -22,7 +22,7 @@ def decode_attention(
     window=None,      # unused: ring-buffer masking arrives via kv_valid
     scale=None,
     kv_chunk: int = 1024,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     B, _, H, D = q.shape
     L, Hkv = k.shape[1], k.shape[2]

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import interpret_mode
+
 f32 = jnp.float32
 
 
@@ -45,7 +47,7 @@ def ea_scores(
     q_var: jax.Array,
     *,
     kc: int = 1024,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     B, Hkv, s_pad, D = k.shape
     rep = q_mu.shape[1]
@@ -62,5 +64,5 @@ def ea_scores(
         ],
         out_specs=pl.BlockSpec((1, 1, kc), lambda b, h, sj: (b, h, sj)),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, s_pad), f32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(k, v, q_mu, q_var)

@@ -21,7 +21,7 @@ def compress(
     *,
     keep: int,
     kc: int = 1024,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     B, S, Hkv, D = k.shape
     kcc = min(kc, max(128, S))

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import interpret_mode
+
 f32 = jnp.float32
 NEG_INF = -1e30
 
@@ -84,7 +86,7 @@ def flash_fwd(
     scale: float | None = None,
     qc: int = 512,
     kc: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     B, H, sq_pad, D = q.shape
     nk = k.shape[2] // kc
@@ -109,5 +111,5 @@ def flash_fwd(
             pltpu.VMEM((qc,), f32),
             pltpu.VMEM((qc, D), f32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)

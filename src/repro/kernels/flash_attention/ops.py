@@ -33,7 +33,7 @@ def flash_attention(
     scale: float | None = None,
     q_chunk: int = 512,
     kv_chunk: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     assert q_offset == 0, "prefill/train always start at position 0"
     B, sq, H, D = q.shape

@@ -6,8 +6,6 @@ K = sample_size and picking the image nearest each centroid (§3.2).
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,17 +16,16 @@ from repro.kernels.kmeans.ref import assign_ref
 f32 = jnp.float32
 
 
-def _pad_rows(x, m):
-    pad = (-x.shape[0]) % m
-    return jnp.pad(x, ((0, pad), (0, 0))) if pad else x
-
-
 def kmeans(
-    x: np.ndarray, k: int, *, iters: int = 10, seed: int = 0,
-    block_n: int = 2048, impl: str = "pallas", interpret: bool = True,
+    x: np.ndarray | jax.Array, k: int, *, iters: int = 10, seed: int = 0,
+    block_n: int = 512, impl: str = "pallas", interpret: bool | None = None,
     init_centroids: np.ndarray | None = None,
 ) -> tuple[np.ndarray, np.ndarray]:
     """Returns (centroids (k, d), assignments (N,)).
+
+    ``x`` may be a host array or the f32 store already on the device; the
+    latter is used in place, so clustering a served store holds no second
+    device copy of it.
 
     ``init_centroids`` warm-starts Lloyd's from a previous clustering
     instead of the seeded random draw — the incremental index rebuild
@@ -40,7 +37,7 @@ def kmeans(
     rng = np.random.default_rng(seed)
     xd = jnp.asarray(x, f32)
     n, d = xd.shape
-    block_n = min(block_n, max(128, n))
+    block_n = min(block_n, max(128, 1 << (n - 1).bit_length()))
     if init_centroids is not None:
         init_centroids = np.asarray(init_centroids, np.float32)
         if init_centroids.ndim != 2 or init_centroids.shape[1] != d:
@@ -51,12 +48,11 @@ def kmeans(
         cent = jnp.asarray(init_centroids[:k], f32)
     else:
         cent = jnp.asarray(x[rng.choice(n, size=k, replace=False)], f32)
-    xp = _pad_rows(xd, block_n)
 
     for _ in range(iters):
         if impl == "pallas":
-            assign = assign_blocks(xp, cent, block_n=block_n,
-                                   interpret=interpret)[:n]
+            assign = assign_blocks(xd, cent, block_n=block_n,
+                                   interpret=interpret)
         else:
             assign = assign_ref(xd, cent)
         sums = jax.ops.segment_sum(xd, assign, num_segments=k)
@@ -67,19 +63,28 @@ def kmeans(
         reseed = jnp.asarray(x[rng.choice(n, size=k)], f32)
         cent = jnp.where(empty[:, None], reseed, new)
     if impl == "pallas":
-        assign = assign_blocks(xp, cent, block_n=block_n,
-                               interpret=interpret)[:n]
+        assign = assign_blocks(xd, cent, block_n=block_n,
+                               interpret=interpret)
     else:
         assign = assign_ref(xd, cent)
     return np.asarray(cent), np.asarray(assign)
 
 
-def medoid_sample(x: np.ndarray, k: int, **kw) -> np.ndarray:
-    """Indices of the k images nearest the k centroids (diverse sample)."""
-    cent, _ = kmeans(x, k, **kw)
-    d2 = (
-        np.sum(x ** 2, axis=1)[:, None]
-        - 2.0 * x @ cent.T
-        + np.sum(cent ** 2, axis=1)[None, :]
-    )
-    return np.unique(np.argmin(d2, axis=0))
+@jax.jit
+def _nearest_rows(x: jax.Array, cent: jax.Array) -> jax.Array:
+    """(k,) row of ``x`` nearest each centroid. ``|c|^2`` is constant per
+    centroid, so it is left out of the argmin over rows."""
+    d2 = (jnp.sum(x * x, axis=1)[:, None]
+          - 2.0 * jnp.dot(x, cent.T, precision=jax.lax.Precision.HIGHEST))
+    return jnp.argmin(d2, axis=0)
+
+
+def medoid_sample(x: np.ndarray | jax.Array, k: int, **kw) -> np.ndarray:
+    """Indices of the k images nearest the k centroids (diverse sample).
+
+    Clustering and the nearest-image pick both run on the device copy of
+    ``x`` (the served store itself when ``x`` is already on the device);
+    only the k indices come back to the host."""
+    xd = jnp.asarray(x, f32)
+    cent, _ = kmeans(xd, k, **kw)
+    return np.unique(np.asarray(_nearest_rows(xd, jnp.asarray(cent))))

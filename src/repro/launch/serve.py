@@ -97,9 +97,11 @@ docs/observability.md.
 from __future__ import annotations
 
 import argparse
+import os
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -131,8 +133,11 @@ def build_stack(dataset: str, *, n_images: int = 1000, sample: int = 32,
                 impl: str = "xla", index_clusters: int = 0,
                 shards: int = 0, split_radius: float = 0.0,
                 balance_boundary: bool = False, ingest: bool = False,
-                rebuild_tail_frac: float = 0.25):
-    corpus = make_corpus(dataset, n_images=n_images, seed=seed)
+                rebuild_tail_frac: float = 0.25, corpus=None):
+    """The serving stack over ``corpus``, or over a fresh
+    ``make_corpus(dataset, n_images=n_images, seed=seed)`` when None."""
+    if corpus is None:
+        corpus = make_corpus(dataset, n_images=n_images, seed=seed)
     mesh = None
     if balance_boundary and (shards <= 0 or index_clusters <= 0):
         raise ValueError("--balance-boundary repartitions the sharded "
@@ -202,7 +207,8 @@ def build_stack(dataset: str, *, n_images: int = 1000, sample: int = 32,
 
     model, mtr = train_specificity(
         X, y, SpecificityModelConfig(embed_dim=corpus.dim, steps=spec_steps))
-    ids = medoid_sample(corpus.images, sample, iters=5, seed=seed)
+    # cluster the store already on the device: no second device copy
+    ids = medoid_sample(hist.embeddings, sample, iters=5, seed=seed)
     store = build_compressed_store(corpus.images, ids, rate=rate, seed=seed)
     spec = SpecificityEstimator(corpus, hist, model)
     kvb = KVBatchEstimator(corpus, hist, store)
@@ -361,6 +367,7 @@ def serve_concurrent(corpus, estimators, queries, *, est_name: str,
                                      chaos=chaos, obs=obs)
 
     failures: list[tuple[int, str]] = []
+    plan_ms = obs.registry.histogram("serve.plan_ms")
     with serving as coal:
 
         def run_one(job):
@@ -374,6 +381,7 @@ def serve_concurrent(corpus, estimators, queries, *, est_name: str,
             except Exception as e:  # noqa: BLE001 — partial failure
                 failures.append((qi, f"{type(e).__name__}: {e}"))
                 return qi, None, False
+            plan_ms.observe((time.perf_counter() - t_q) * 1e3)
             fb = est if (feedback and hasattr(est, "observe")) else None
             res = execute_cascade(corpus, plan, seed=seed, obs=obs,
                                   est_name=est_name, feedback=fb)
@@ -421,6 +429,19 @@ def serve_concurrent(corpus, estimators, queries, *, est_name: str,
     if failures:
         print(f"  first failure: {failures[0][1]}")
     return stats
+
+
+def use_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache where
+    ``JAX_COMPILATION_CACHE_DIR`` says (JAX reads the variable itself), or
+    else at ``<repo>/.jax_cache``: a fixed path, so one run's compiled
+    programs are found again by the next. Returns the directory."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def main(argv=None) -> None:
@@ -558,6 +579,7 @@ def main(argv=None) -> None:
     if args.replicas > 1 and args.concurrency <= 1:
         ap.error("--replicas serves through the concurrent path — it "
                  "needs --concurrency > 1")
+    use_compile_cache()
     tracer = (Tracer(args.trace_out, sample=args.trace_sample)
               if args.trace_out else None)
     hub = ObsHub(tracer=tracer)
@@ -621,6 +643,12 @@ def main(argv=None) -> None:
         tracer.close()
         print(f"trace spans -> {args.trace_out} "
               f"({tracer.emitted} records, sample=1/{args.trace_sample})")
+    failed = hub.registry.counter("serve.failed_queries").value
+    if failed and not args.chaos:
+        # without injected faults every query must plan: a run whose
+        # probes fail must not exit 0
+        raise SystemExit(f"{failed} queries failed — see 'first failure' "
+                         f"above")
 
 
 if __name__ == "__main__":

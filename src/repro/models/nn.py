@@ -218,13 +218,9 @@ def _current_mesh() -> Mesh | None:
     m = _MESH_CTX.get()
     if m is not None and not m.empty:
         return m
-    try:
-        from jax._src.mesh import thread_resources
-
-        m = thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:  # pragma: no cover - jax internals moved
-        return None
+    # the mesh jax.set_mesh installed, as tracing sees it
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 # ---------------------------------------------------------------------------

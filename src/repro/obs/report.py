@@ -26,7 +26,8 @@ Snapshot schema (``schema`` bumps on breaking change):
   index         index.stats() verbatim (absent without an index);
                 ``mutable`` flags the MutableClusteredStore form
   latency_ms    per-phase {count, p50, p95, p99, ...} summaries for
-                queue_wait / probe / combine / request
+                queue_wait / probe / combine / request (per predicate)
+                and plan (per query, the concurrent path's plan wall)
   qerror        per-estimator exact-q-error histogram summaries
   degraded_answers  interval-width summary + containment counters
   serve         wall_s / qps / queries / degraded_plans / failed_queries
@@ -48,7 +49,7 @@ RECONCILE_BUCKETS = ("probe_scored", "cache_hits", "coalesced_dups",
 # resolve into their own bucket, so the invariant stays exact with hedging
 FLEET_RECONCILE_BUCKETS = RECONCILE_BUCKETS + ("hedge_cancelled",)
 
-_PHASES = ("queue_wait", "probe", "combine", "request")
+_PHASES = ("queue_wait", "probe", "combine", "request", "plan")
 
 
 def build_snapshot(*, registry, coalescer: dict | None = None,

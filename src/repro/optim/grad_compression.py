@@ -116,9 +116,7 @@ def two_stage_allreduce(
     def body(grads):
         return jax.tree.map(reduce_one, grads)
 
-    from jax.experimental.shard_map import shard_map
-
     specs = in_specs or jax.tree.map(lambda _: P(), local_grads)
-    return shard_map(
-        body, mesh=mesh, in_specs=(specs,), out_specs=specs, check_rep=False
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=(specs,), out_specs=specs, check_vma=False
     )(local_grads)

@@ -75,3 +75,13 @@ def test_roofline_terms_and_bottleneck():
     assert r.useful_ratio == pytest.approx(1.0, rel=0.01)
     assert r.bottleneck in ("compute", "memory")
     assert r.compute_term > 0 and r.memory_term > 0
+
+
+def test_peaks_table_raises_for_unknown_kind_and_keeps_link_rate():
+    from repro.analysis.roofline import V5E, peaks
+
+    # 1,600 Gbit/s per chip over 4 ICI links: 50 GB/s on the ring link
+    # the collective wire formulas count
+    assert peaks(V5E).link_bw == pytest.approx(50e9)
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks("TPU v9 imaginary")

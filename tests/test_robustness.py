@@ -670,3 +670,19 @@ def test_chaos_storm_with_full_telemetry_reconciles(rng, tmp_path):
     assert any(d for *_, d in traced), "chaos must actually degrade some"
     for name in ("requests", "probe_scored", "degraded", "errors"):
         assert st_a[name] == st_b[name], name
+
+
+def test_serve_main_exits_nonzero_when_queries_fail(monkeypatch):
+    """Without --chaos every query must plan: a run whose probes all fail
+    exits non-zero instead of counting the failures and returning."""
+    from repro.launch import serve
+
+    def failing_plan(*a, **kw):
+        raise RuntimeError("probe failed")
+
+    monkeypatch.setattr(serve, "plan_query", failing_plan)
+    monkeypatch.setattr(serve, "use_compile_cache", lambda: "")
+    with pytest.raises(SystemExit) as exc:
+        serve.main(["--concurrency", "2", "--queries", "2", "--filters", "2",
+                    "--passes", "1", "--n-images", "300"])
+    assert "2 queries failed" in str(exc.value.code)
