@@ -41,6 +41,7 @@ from repro.core.kvbatch import (
 )
 from repro.core.specificity import SpecificityModel
 from repro.core.synthetic import Corpus
+from repro.obs import spans
 
 
 @dataclasses.dataclass
@@ -87,7 +88,8 @@ class SpecificityEstimator:
 
     def _thresholds(self, embs: np.ndarray) -> np.ndarray:
         """Batched MLP thresholds — one jitted apply for the whole batch."""
-        return self.model.thresholds(embs)
+        with spans.span(spans.PLAN_SPECIFICITY):
+            return self.model.thresholds(embs)
 
     def estimate(self, node_id: int, seed: int = 0) -> Estimate:
         t0 = time.perf_counter()
@@ -104,9 +106,11 @@ class SpecificityEstimator:
         coalescer handle) replacing the direct histogram probe."""
         sel_batch = probe if probe is not None else self.hist.selectivity_batch
         t0 = time.perf_counter()
-        embs = _predicate_embeddings(self.corpus, node_ids, seed)
+        with spans.span(spans.PLAN_EMBED):
+            embs = _predicate_embeddings(self.corpus, node_ids, seed)
         thrs = self._thresholds(embs)
-        sels = sel_batch(embs, thrs)
+        with spans.span(spans.PLAN_PROBE):
+            sels = sel_batch(embs, thrs)
         dt = (time.perf_counter() - t0) / max(1, len(node_ids))
         return [Estimate(float(s), dt, vlm_calls=0.0, threshold=float(t))
                 for s, t in zip(sels, thrs)]
@@ -143,12 +147,15 @@ class KVBatchEstimator:
         """Batched §3.2 calibration: (thresholds (B,), sample matches (B,)).
         One (S, d) x (d, B) distance matmul for the whole predicate batch;
         the batched decode machinery runs once regardless of B."""
-        ids = self.store.sample_ids
-        dists = 1.0 - self.corpus.images[ids] @ embs.T      # (S, B)
-        ms = np.asarray([int(self.corpus.vlm_answer(n, ids, seed=seed).sum())
-                         for n in node_ids])
-        thrs = np.asarray([threshold_from_matches(dists[:, j], int(ms[j]))
-                           for j in range(len(node_ids))])
+        with spans.span(spans.PLAN_KVBATCH):
+            ids = self.store.sample_ids
+            dists = 1.0 - self.corpus.images[ids] @ embs.T      # (S, B)
+            ms = np.asarray([int(self.corpus.vlm_answer(n, ids,
+                                                        seed=seed).sum())
+                             for n in node_ids])
+            thrs = np.asarray([threshold_from_matches(dists[:, j],
+                                                      int(ms[j]))
+                               for j in range(len(node_ids))])
         return thrs, ms
 
     def estimate(self, node_id: int, seed: int = 0) -> Estimate:
@@ -177,9 +184,11 @@ class KVBatchEstimator:
         sel_batch = probe if probe is not None else self.hist.selectivity_batch
         machine_s = self._machinery_latency()
         t0 = time.perf_counter()
-        embs = _predicate_embeddings(self.corpus, node_ids, seed)
+        with spans.span(spans.PLAN_EMBED):
+            embs = _predicate_embeddings(self.corpus, node_ids, seed)
         thrs, ms = self._thresholds(node_ids, embs, seed)
-        sels = sel_batch(embs, thrs)
+        with spans.span(spans.PLAN_PROBE):
+            sels = sel_batch(embs, thrs)
         dt = (time.perf_counter() - t0) / max(1, len(node_ids))
         return [Estimate(float(s), dt, vlm_calls=1.0, threshold=float(t),
                          extra={"sample_matches": int(m),
@@ -327,11 +336,13 @@ class EnsembleEstimator:
         sel_batch = probe if probe is not None else self.hist.selectivity_batch
         machine_s = self.kvb._machinery_latency()
         t0 = time.perf_counter()
-        embs = _predicate_embeddings(self.corpus, node_ids, seed)
+        with spans.span(spans.PLAN_EMBED):
+            embs = _predicate_embeddings(self.corpus, node_ids, seed)
         t_spec = self.spec._thresholds(embs)
         t_kvb, ms = self.kvb._thresholds(node_ids, embs, seed)
         thrs = 0.5 * (t_spec + t_kvb)
-        sels = sel_batch(embs, thrs)
+        with spans.span(spans.PLAN_PROBE):
+            sels = sel_batch(embs, thrs)
         dt = (time.perf_counter() - t0) / max(1, len(node_ids))
         out = []
         for j, (s, t, m) in enumerate(zip(sels, thrs, ms)):

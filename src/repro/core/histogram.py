@@ -662,8 +662,9 @@ def make_sharded_pruned_probe(mesh, index, *, k: int = 128,
     the per-row distances drift an ulp from the full scan's and bitwise
     parity dies (optimization_barrier does not stop it). Materializing the
     per-shard buckets between two shard_maps pins the scan's operand, the
-    same reason ``ClusteredStore._gather`` runs its ``jnp.take`` eagerly
-    outside the jitted masked probe.
+    same reason ``ClusteredStore._gather`` runs its gather
+    (``index.clustered.gather_rows``) as a program apart from the jitted
+    masked probe.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -681,9 +682,11 @@ def make_sharded_pruned_probe(mesh, index, *, k: int = 128,
         store = jax.device_put(index.embeddings,
                                NamedSharding(mesh, P(data_axes)))
 
+    def gather_rows(store_l, idx_l):    # jit_gather_rows on the trace
+        return jnp.take(store_l, idx_l[0], axis=0)
+
     gather = jax.jit(jax.shard_map(
-        lambda store_l, idx_l: jnp.take(store_l, idx_l[0], axis=0),
-        mesh=mesh, in_specs=(P(data_axes), P(data_axes)),
+        gather_rows, mesh=mesh, in_specs=(P(data_axes), P(data_axes)),
         out_specs=P(data_axes), check_vma=False,
     ))
 
@@ -748,6 +751,7 @@ def make_sharded_pruned_probe(mesh, index, *, k: int = 128,
             # exactly the worst case of the full-scan path and no more.
             # Disabled under tombstones: dead rows must never be scanned.
             buf = store
+            bucket = index.shard_rows
             nv = np.full(n_shards, index.shard_rows, np.int32)
         else:
             bucket = min(max(128, 1 << (max(m_max, kk) - 1).bit_length()),
@@ -766,7 +770,8 @@ def make_sharded_pruned_probe(mesh, index, *, k: int = 128,
             extra = extra[:, 0, :]                          # (S, T)
         counts, top = sharded(buf, jnp.asarray(nv), jnp.asarray(extra),
                               jnp.asarray(preds), jnp.asarray(thr))
-        index.record(plans, launched=True, live_n=live_n)
+        index.record(plans, launched=True, live_n=live_n,
+                     gathered=[bucket] * n_shards)
         return np.asarray(counts), np.asarray(top)
 
     return probe

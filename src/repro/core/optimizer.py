@@ -22,6 +22,7 @@ import numpy as np
 
 from repro.core.estimators import Estimate
 from repro.core.synthetic import Corpus
+from repro.obs import spans
 
 # ~0.15 s/call: 7B bf16 decode w/ short answer on one v5e host slice
 # (2*7e9 FLOPs/token / (8 chips * 197e12) plus weight streaming; matches the
@@ -166,6 +167,13 @@ def plan_query(filters: Sequence[int], estimator, seed: int = 0,
     assumption; ``QueryPlan.prefix_sels`` then carries the estimated joint
     selectivity of every cascade prefix. Degraded (bound-only) plans keep
     the interval-midpoint order: a compound probe cannot certify bounds."""
+    with spans.plan_span(len(filters)):
+        return _plan_query(filters, estimator, seed, coalescer,
+                           deadline_ms, degraded_ok, compound)
+
+
+def _plan_query(filters, estimator, seed, coalescer, deadline_ms,
+                degraded_ok, compound) -> QueryPlan:
     t0 = time.perf_counter()
     batch = getattr(estimator, "estimate_batch", None)
     wrapper = None

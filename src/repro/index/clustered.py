@@ -64,11 +64,25 @@ from repro.kernels.cosine_topk.ref import (
     cosine_probe_batch_masked_ref,
 )
 from repro.kernels.kmeans.ops import kmeans
+from repro.obs import spans
 
 f32 = jnp.float32
 
 __all__ = ["ClusteredStore", "ScanPlan", "build_clustered_store",
-           "store_from_fragments"]
+           "gather_rows", "store_from_fragments"]
+
+
+@jax.jit
+def gather_rows(store, rows):
+    """The scan buffer ``store[rows]``, as a device program of its own.
+
+    Jitted at module level so the gather carries one name on the device
+    trace (``jit_gather_rows``). It stays apart from the masked scan:
+    fused into one program, XLA folds the gather into the distance
+    contraction and per-row distances drift from the full scan's
+    (``make_sharded_pruned_probe``'s docstring in core/histogram.py).
+    """
+    return jnp.take(store, rows, axis=0)
 
 
 @partial(jax.jit, static_argnames=("k",))
@@ -162,10 +176,12 @@ class ClusteredStore:
         self.n = int(self.embeddings.shape[0])
         self.k_clusters = int(self.sizes.shape[0])
         self._lock = threading.Lock()
+        # rows_gathered: rows the kernel reads, power-of-two padding
+        # included (rows_scanned counts the valid ones)
         self._cum = {"probes": 0, "launches": 0, "rows_scanned": 0,
-                     "rows_full_equiv": 0}
+                     "rows_full_equiv": 0, "rows_gathered": 0}
         # telemetry hub (repro.obs.ObsHub), attached by the serve layer;
-        # duck-typed so the index never imports the obs package
+        # duck-typed so the index never imports the hub
         self.obs = None
 
     # ------------------------------------------------------------- bounds
@@ -276,35 +292,38 @@ class ClusteredStore:
         live rows only, and the full-store promotion compares against the
         live total (dead rows are never gathered, see ``scan_rows``).
         """
-        sizes = self.sizes if live_sizes is None else \
-            np.asarray(live_sizes, np.int64)
-        n_live = int(sizes.sum())
-        lb, ub = self.cluster_bounds(preds)                  # (B, K) f64
-        thr64 = np.asarray(thr, np.float64)
-        allin = ub[:, :, None] <= thr64[:, None, :] - self.eps   # (B, K, T)
-        allout = lb[:, :, None] > thr64[:, None, :] + self.eps
-        nonempty = sizes > 0
-        boundary = (~(allin | allout)).any(axis=2) & nonempty[None, :]
-        scan_bk = boundary.copy()                            # (B, K)
-        if need_topk:
-            scan_bk |= self._topk_cover(
-                lb, ub, max(1, min(int(k), max(n_live, 1))), sizes)
-        in_union = scan_bk.any(axis=0) & nonempty            # (K,)
-        scan_ids = np.flatnonzero(in_union)
-        if int(sizes[scan_ids].sum()) >= 0.9 * n_live:
-            in_union = nonempty.copy()
+        with spans.span(spans.INDEX_PLAN_SCAN):
+            sizes = self.sizes if live_sizes is None else \
+                np.asarray(live_sizes, np.int64)
+            n_live = int(sizes.sum())
+            lb, ub = self.cluster_bounds(preds)                  # (B, K) f64
+            thr64 = np.asarray(thr, np.float64)
+            # (B, K, T)
+            allin = ub[:, :, None] <= thr64[:, None, :] - self.eps
+            allout = lb[:, :, None] > thr64[:, None, :] + self.eps
+            nonempty = sizes > 0
+            boundary = (~(allin | allout)).any(axis=2) & nonempty[None, :]
+            scan_bk = boundary.copy()                            # (B, K)
+            if need_topk:
+                scan_bk |= self._topk_cover(
+                    lb, ub, max(1, min(int(k), max(n_live, 1))), sizes)
+            in_union = scan_bk.any(axis=0) & nonempty            # (K,)
             scan_ids = np.flatnonzero(in_union)
-        # clusters resolved by bounds alone: add all-in sizes. The scan
-        # buffer is scored against *every* predicate, so any cluster in the
-        # union — even one this predicate classified all-in — is counted
-        # row-by-row by the kernel, exactly; only clusters outside the
-        # union contribute via their bound classification.
-        resolved = nonempty[None, :] & ~in_union[None, :]    # (B, K)
-        extra = ((allin & resolved[:, :, None]).astype(np.int64)
-                 * sizes[None, :, None]).sum(axis=1)         # (B, T)
-        return ScanPlan(scan_ids=scan_ids,
-                        m=int(sizes[scan_ids].sum()), extra=extra,
-                        boundary_clusters=int(boundary.sum()))
+            if int(sizes[scan_ids].sum()) >= 0.9 * n_live:
+                in_union = nonempty.copy()
+                scan_ids = np.flatnonzero(in_union)
+            # clusters resolved by bounds alone: add all-in sizes. The
+            # scan buffer is scored against *every* predicate, so any
+            # cluster in the union — even one this predicate classified
+            # all-in — is counted row-by-row by the kernel, exactly; only
+            # clusters outside the union contribute via their bound
+            # classification.
+            resolved = nonempty[None, :] & ~in_union[None, :]    # (B, K)
+            extra = ((allin & resolved[:, :, None]).astype(np.int64)
+                     * sizes[None, :, None]).sum(axis=1)         # (B, T)
+            return ScanPlan(scan_ids=scan_ids,
+                            m=int(sizes[scan_ids].sum()), extra=extra,
+                            boundary_clusters=int(boundary.sum()))
 
     def scan_rows(self, cluster_ids: np.ndarray,
                   live: np.ndarray | None = None) -> np.ndarray:
@@ -337,21 +356,22 @@ class ClusteredStore:
         tombstones (``live``) the zero-copy shortcut is disabled because
         dead rows must never enter the scan.
         """
-        if live is None:
-            m = int(self.sizes[cluster_ids].sum())
-            if m == self.n:
-                return self.embeddings, m
-            rows = self.scan_rows(cluster_ids)
-        else:
-            sizes = self.live_cluster_sizes(live) if live_sizes is None \
-                else live_sizes
-            m = int(np.asarray(sizes)[cluster_ids].sum())
-            rows = self.scan_rows(cluster_ids, live)
-        bucket = max(128, 1 << max(0, m - 1).bit_length())
-        pad = np.zeros(bucket - m, np.int64)
-        buf = jnp.take(self.embeddings,
-                       jnp.asarray(np.concatenate([rows, pad])), axis=0)
-        return buf, m
+        with spans.span(spans.INDEX_GATHER):
+            if live is None:
+                m = int(self.sizes[cluster_ids].sum())
+                if m == self.n:
+                    return self.embeddings, m
+                rows = self.scan_rows(cluster_ids)
+            else:
+                sizes = self.live_cluster_sizes(live) \
+                    if live_sizes is None else live_sizes
+                m = int(np.asarray(sizes)[cluster_ids].sum())
+                rows = self.scan_rows(cluster_ids, live)
+            bucket = max(128, 1 << max(0, m - 1).bit_length())
+            pad = np.zeros(bucket - m, np.int64)
+            buf = gather_rows(self.embeddings,
+                              jnp.asarray(np.concatenate([rows, pad])))
+            return buf, m
 
     def _masked_probe(self, buf, m, preds, thr, *, k, impl, interpret,
                       scalar):
@@ -363,20 +383,22 @@ class ClusteredStore:
         ``probe_batch`` without an index still runs the batch kernel —
         to keep pruned results bitwise equal to the full scan.
         """
-        nv = jnp.asarray(m, jnp.int32)
-        if impl == "pallas":
-            from repro.kernels.cosine_topk import ops as ct
+        with spans.span(spans.INDEX_SCAN):
+            nv = jnp.asarray(m, jnp.int32)
+            if impl == "pallas":
+                from repro.kernels.cosine_topk import ops as ct
 
+                if scalar:
+                    counts, topk = ct.cosine_probe_masked(
+                        buf, nv, preds[0], thr[0], k=k, interpret=interpret)
+                    return counts[None], topk[None]
+                return ct.cosine_probe_batch_masked(buf, nv, preds, thr, k=k,
+                                                    interpret=interpret)
             if scalar:
-                counts, topk = ct.cosine_probe_masked(
-                    buf, nv, preds[0], thr[0], k=k, interpret=interpret)
+                counts, topk = _masked_probe_xla(buf, nv, preds[0], thr[0],
+                                                 k=k)
                 return counts[None], topk[None]
-            return ct.cosine_probe_batch_masked(buf, nv, preds, thr, k=k,
-                                                interpret=interpret)
-        if scalar:
-            counts, topk = _masked_probe_xla(buf, nv, preds[0], thr[0], k=k)
-            return counts[None], topk[None]
-        return _masked_probe_batch_xla(buf, nv, preds, thr, k=k)
+            return _masked_probe_batch_xla(buf, nv, preds, thr, k=k)
 
     # -------------------------------------------------------------- probe
 
@@ -424,11 +446,12 @@ class ClusteredStore:
 
         if len(plan.scan_ids) and plan.m:
             buf, m = self._gather(plan.scan_ids, live, live_sizes)
+            gathered = int(buf.shape[0])
             counts_s, topk = self._masked_probe(
                 buf, m, jnp.asarray(preds), jnp.asarray(thr), k=k,
                 impl=impl, interpret=interpret, scalar=scalar_kernel)
         else:                       # every cluster resolved by its bounds
-            m = 0
+            m = gathered = 0
             counts_s = np.zeros((b, t), np.int32)
             topk = jnp.full((b, k), jnp.inf, f32)
 
@@ -438,6 +461,7 @@ class ClusteredStore:
         stats = {
             "launches": 1 if m else 0,
             "rows_scanned": m,
+            "rows_gathered": gathered,
             "rows_full_equiv": n_eff,
             "scan_fraction": m / max(1, n_eff),
             "scanned_clusters": int(len(plan.scan_ids)),
@@ -558,19 +582,20 @@ class ClusteredStore:
             m = int(len(rows))
             bucket = max(128, 1 << max(0, m - 1).bit_length())
             pad = np.zeros(bucket - m, np.int64)
-            buf = jnp.take(self.embeddings,
-                           jnp.asarray(np.concatenate([rows, pad])), axis=0)
+            buf = gather_rows(self.embeddings,
+                              jnp.asarray(np.concatenate([rows, pad])))
             scanned = int(_compound_masked_xla(
                 buf, jnp.asarray(m, jnp.int32), jnp.asarray(preds),
                 jnp.asarray(thr), mode=mode))
         else:
-            m = 0
+            m = bucket = 0
             scanned = 0
         count = scanned + int(plan.extra[0, 0])
 
         stats = {
             "launches": 1 if m else 0,
             "rows_scanned": m,
+            "rows_gathered": bucket,
             "rows_full_equiv": n_eff,
             "scan_fraction": m / max(1, n_eff),
             "scanned_clusters": int(len(plan.scan_ids)),
@@ -609,7 +634,7 @@ class ClusteredStore:
         preds_j = jnp.asarray(pred)[None, :]
         thr_j = jnp.zeros((1, 1), f32)
         best = np.empty(0, np.float32)
-        i, launches, rows_scanned = 0, 0, 0
+        i, launches, rows_scanned, rows_gathered = 0, 0, 0, 0
         # chunk target: enough rows per launch to amortize dispatch without
         # defeating early termination on small stores
         target = max(k, min(self.chunk_rows, max(1, n_eff // 8)))
@@ -629,8 +654,10 @@ class ClusteredStore:
                            kind="stable")[:k]
             launches += 1
             rows_scanned += m
+            rows_gathered += int(buf.shape[0])
             i = j
         self._record({"launches": launches, "rows_scanned": rows_scanned,
+                      "rows_gathered": rows_gathered,
                       "rows_full_equiv": n_eff}, probes=1)
         return float(best[k - 1])
 
@@ -641,6 +668,7 @@ class ClusteredStore:
             self._cum["probes"] += probes
             self._cum["launches"] += stats["launches"]
             self._cum["rows_scanned"] += stats["rows_scanned"]
+            self._cum["rows_gathered"] += stats.get("rows_gathered", 0)
             self._cum["rows_full_equiv"] += stats["rows_full_equiv"]
             frac = (self._cum["rows_scanned"]
                     / max(1, self._cum["rows_full_equiv"]))
@@ -650,7 +678,8 @@ class ClusteredStore:
 
     def stats(self) -> dict:
         """Cumulative scan accounting; ``scan_fraction`` is rows actually
-        streamed over rows a full-scan probe would have streamed."""
+        streamed over rows a full-scan probe would have streamed, and
+        ``rows_gathered`` the rows the kernel read, padding included."""
         with self._lock:
             d = dict(self._cum)
         d["scan_fraction"] = (d["rows_scanned"]

@@ -67,6 +67,7 @@ import numpy as np
 from repro.index.clustered import (
     ClusteredStore,
     build_clustered_store,
+    gather_rows,
     store_from_fragments,
 )
 
@@ -187,21 +188,24 @@ class ShardedClusteredStore:
                  for s, ls in zip(self.shards, live_sizes)]
         count = sum(int(p.extra[0, 0]) for p in plans)
         rows_scanned = 0
-        for shard, plan, lv in zip(self.shards, plans, live):
+        gathered = [0] * self.n_shards
+        for s, (shard, plan, lv) in enumerate(zip(self.shards, plans, live)):
             if not (len(plan.scan_ids) and plan.m):
                 continue
             rows = shard.scan_rows(plan.scan_ids, lv)
             m = int(len(rows))
             rows_scanned += m
             bucket = max(128, 1 << max(0, m - 1).bit_length())
+            gathered[s] = bucket
             pad = np.zeros(bucket - m, np.int64)
-            buf = jnp.take(shard.embeddings,
-                           jnp.asarray(np.concatenate([rows, pad])), axis=0)
+            buf = gather_rows(shard.embeddings,
+                              jnp.asarray(np.concatenate([rows, pad])))
             count += int(_compound_masked_xla(
                 buf, jnp.asarray(m, jnp.int32), jnp.asarray(preds),
                 jnp.asarray(thr), mode=mode))
         launched = rows_scanned > 0
-        self.record(plans, launched=launched, live_n=live_n)
+        self.record(plans, launched=launched, live_n=live_n,
+                    gathered=gathered)
         nl = live_n if live_n is not None else [s.n for s in self.shards]
         n_eff = sum(int(x) for x in nl)
         stats = {
@@ -219,17 +223,23 @@ class ShardedClusteredStore:
     # -------------------------------------------------------------- stats
 
     def record(self, plans: list, *, launched: bool,
-               live_n: list | None = None) -> None:
+               live_n: list | None = None,
+               gathered: list | None = None) -> None:
         """Account one sharded probe: per-shard rows into each sub-index
         (their scan fractions diverge when boundary work is uneven), the
         probe/launch tally here. ``live_n`` — per-shard live row counts
         under tombstones — replaces ``shard.n`` as the full-scan-equivalent
-        denominator."""
+        denominator. ``gathered`` — per-shard rows the kernel read, the
+        power-of-two bucket with its padding — defaults to each shard's
+        scanned rows."""
         if live_n is None:
             live_n = [s.n for s in self.shards]
-        for shard, plan, nl in zip(self.shards, plans, live_n):
+        if gathered is None:
+            gathered = [p.m for p in plans]
+        for shard, plan, nl, g in zip(self.shards, plans, live_n, gathered):
             shard._record({"launches": 1 if (launched and plan.m) else 0,
                            "rows_scanned": plan.m if launched else 0,
+                           "rows_gathered": int(g) if launched else 0,
                            "rows_full_equiv": int(nl)}, probes=1)
         rows = sum(p.m for p in plans) if launched else 0
         full = sum(int(nl) for nl in live_n)
@@ -277,6 +287,7 @@ class ShardedClusteredStore:
         with self._lock:
             d = {"probes": self._probes, "launches": self._launches}
         d["rows_scanned"] = sum(p["rows_scanned"] for p in per)
+        d["rows_gathered"] = sum(p["rows_gathered"] for p in per)
         d["rows_full_equiv"] = sum(p["rows_full_equiv"] for p in per)
         d["scan_fraction"] = (d["rows_scanned"]
                               / max(1, d["rows_full_equiv"]))

@@ -63,13 +63,14 @@ raised without a bound answer.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 import time
 from collections import OrderedDict
 
 import numpy as np
 
-from repro.obs import ObsHub, set_flush_ctx
+from repro.obs import ObsHub, set_flush_ctx, spans
 from repro.runtime.fault_tolerance import (
     CircuitBreaker,
     RetryPolicy,
@@ -341,6 +342,9 @@ class PredicateCoalescer:
         flusher_deaths     flusher thread deaths observed
         flusher_restarts   replacement flusher threads started
         queue_depth_hwm    max pending-queue depth ever observed
+        flush_lag_us       microseconds the flusher was free while a batch
+                           was due and not yet taken (summed over flushes;
+                           its wake-up lag, e.g. under the interpreter lock)
 
     Coalescing wins show up as ``probes_fired`` << ``requests`` and
     cache + dedup wins as ``predicates_probed`` < ``requests``.
@@ -349,7 +353,8 @@ class PredicateCoalescer:
     _COUNTERS = ("requests", "probes_fired", "predicates_probed",
                  "probe_scored", "cache_hits", "coalesced_dups", "shed",
                  "degraded", "errors", "retries", "probe_failures",
-                 "breaker_fastfails", "flusher_deaths", "flusher_restarts")
+                 "breaker_fastfails", "flusher_deaths", "flusher_restarts",
+                 "flush_lag_us")
 
     def __init__(self, hist, config: CoalescerConfig | None = None, *,
                  cache: PredicateCache | None = None, chaos=None,
@@ -391,6 +396,8 @@ class PredicateCoalescer:
         self._pending: list[_Pending] = []
         self._inflight: dict[tuple, _Pending] = {}
         self._stop = False
+        self._flush_ids = itertools.count(1)   # flush ids without a tracer
+        self._flush_end = -float("inf")        # end of the previous flush
         self._flusher = self._spawn_flusher()
 
     def _on_breaker_transition(self, old: str, new: str) -> None:
@@ -448,6 +455,14 @@ class PredicateCoalescer:
         ``ProbeOutcome``s instead of raises. Every request resolves into
         exactly one reconciliation bucket (module docstring).
         """
+        plan = spans.current_plan()
+        ids = {} if plan is None else {"plan": plan}
+        with spans.span(spans.COALESCER_SUBMIT, **ids):
+            return self._probe_outcomes(preds, thresholds, deadline,
+                                        degraded_ok)
+
+    def _probe_outcomes(self, preds, thresholds, deadline, degraded_ok
+                        ) -> list[ProbeOutcome]:
         preds = np.asarray(preds, np.float32)
         thrs = np.asarray(thresholds, np.float32).reshape(-1)
         if preds.ndim != 2 or preds.shape[0] != thrs.shape[0]:
@@ -610,7 +625,7 @@ class PredicateCoalescer:
     def _take_batch(self) -> list[_Pending] | None:
         """Block until a window closes (size or timeout); pop its batch."""
         window_s = self.cfg.window_ms / 1e3
-        with self._cv:
+        with spans.span(spans.COALESCER_AWAIT_BATCH), self._cv:
             while not self._pending:
                 if self._stop:
                     return None
@@ -625,7 +640,25 @@ class PredicateCoalescer:
                 self._cv.wait(timeout=remaining)
             batch = self._pending[:self.cfg.max_batch]
             del self._pending[:len(batch)]
+            self._count_flush_lag(batch, time.monotonic())
             return batch
+
+    def _count_flush_lag(self, batch: list[_Pending], t_take: float) -> None:
+        """Add to ``flush_lag_us`` how long ``batch`` was due while the
+        flusher was free, up to ``t_take``, the moment it was popped.
+
+        The batch fell due when its window closed or when it filled,
+        whichever came first; the flusher was free for it from the end of
+        the previous flush. Time inside a flush (stacking, dispatch, copy
+        back) is never lag. A ``flush_now()`` batch (ts -inf) was due at
+        once and counts no lag, as ``qw_s`` clamps its queue wait.
+        """
+        due = batch[0].ts + self.cfg.window_ms / 1e3
+        if len(batch) >= self.cfg.max_batch:
+            due = min(due, batch[self.cfg.max_batch - 1].ts)
+        lag = t_take - max(due, self._flush_end)
+        if due > -float("inf") and lag > 0:
+            self._c["flush_lag_us"].inc(int(lag * 1e6))
 
     def _flush(self, batch: list[_Pending]) -> None:
         """One batched probe for the window; scatter + cache-fill.
@@ -642,12 +675,18 @@ class PredicateCoalescer:
         b = len(batch)
         bucket = 1 << (b - 1).bit_length()
         bucket = min(max(bucket, 1), max(self.cfg.max_batch, b))
+        tr = self.obs.tracer
+        flush_id = tr.next_id() if tr is not None else next(self._flush_ids)
+        with spans.span(spans.COALESCER_FLUSH, flush=flush_id, batch=b,
+                        bucket=bucket):
+            self._flush_batch(batch, b, bucket, tr, flush_id)
+        self._flush_end = time.monotonic()
+
+    def _flush_batch(self, batch, b, bucket, tr, flush_id) -> None:
         embs = np.stack([p.emb for p in batch]
                         + [batch[-1].emb] * (bucket - b))
         thrs = np.asarray([p.thr for p in batch]
                           + [batch[-1].thr] * (bucket - b), np.float32)
-        tr = self.obs.tracer
-        flush_id = tr.next_id() if tr is not None else None
         t_dq = time.monotonic()
         for p in batch:
             # flush_now backdates ts to -inf; clamp so the breakdown
@@ -665,9 +704,11 @@ class PredicateCoalescer:
                     break
                 t0 = time.perf_counter()
                 try:
-                    counts, topk = self._probe(embs, thrs)
-                    counts = np.asarray(counts)
-                    topk = np.asarray(topk)
+                    with spans.span(spans.HIST_PROBE):
+                        counts, topk = self._probe(embs, thrs)
+                    with spans.span(spans.HIST_COPY_BACK):
+                        counts = np.asarray(counts)
+                        topk = np.asarray(topk)
                     self.breaker.record_success()
                     probe_s = time.perf_counter() - t0
                     self.watchdog.observe(probe_s)
@@ -694,16 +735,17 @@ class PredicateCoalescer:
             self._c["probes_fired"].inc()
             self._c["predicates_probed"].inc(b)
         t_sc = time.monotonic()
-        for i, p in enumerate(batch):
-            if err is None:
-                p.value = (counts[i].copy(), topk[i].copy())
-                self.cache.put(p.key, p.value)
-                p.probe_s = probe_s
-            else:
-                p.error = err
-            with self._cv:
-                self._inflight.pop(p.key, None)
-            p.event.set()
+        with spans.span(spans.COALESCER_SCATTER):
+            for i, p in enumerate(batch):
+                if err is None:
+                    p.value = (counts[i].copy(), topk[i].copy())
+                    self.cache.put(p.key, p.value)
+                    p.probe_s = probe_s
+                else:
+                    p.error = err
+                with self._cv:
+                    self._inflight.pop(p.key, None)
+                p.event.set()
         if tr is not None:
             tr.emit("flush", flush=flush_id, batch=b, bucket=bucket,
                     queue_wait_ms=round(batch[0].qw_s * 1e3, 4),

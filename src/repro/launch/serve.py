@@ -90,13 +90,17 @@ snapshot as schema-versioned JSON, and ``--trace-out PATH`` with
 ``--trace-sample N`` streams 1-in-N per-request trace spans (submit /
 flush / scan / plan / event) as JSONL with a closing reconciliation
 summary. Telemetry observes host-side only — probe results stay
-bitwise identical with it on or off. Schema + tuning:
+bitwise identical with it on or off. ``--profile-dir DIR`` records the
+serving phase in a ``jax.profiler`` trace under DIR: the program's spans
+(plan, coalescer, histogram and index boundaries; ``repro.obs.spans``)
+on the same clock as the device's ops. Schema + tuning:
 docs/observability.md.
 """
 
 from __future__ import annotations
 
 import argparse
+import contextlib
 import os
 import threading
 import time
@@ -571,6 +575,12 @@ def main(argv=None) -> None:
     ap.add_argument("--trace-sample", type=int, default=1,
                     help="trace 1-in-N requests per span kind (1 = every "
                          "request; raise under load to bound overhead)")
+    ap.add_argument("--profile-dir", default="",
+                    help="record the serving phase in a jax.profiler "
+                         "trace under this directory: the program's "
+                         "spans beside the device's ops (open with "
+                         "TensorBoard's profile plugin, or read with "
+                         "jax.profiler.ProfileData)")
     args = ap.parse_args(argv)
 
     if args.ingest_rate > 0 and args.concurrency <= 1:
@@ -600,22 +610,27 @@ def main(argv=None) -> None:
     queries = generate_queries(corpus, n_queries=args.queries,
                                n_filters=args.filters, seed=args.seed)
     stats = None
-    if args.concurrency > 1:
-        stats = serve_concurrent(
-            corpus, estimators, queries, est_name=args.estimator,
-            seed=args.seed, concurrency=args.concurrency,
-            window_ms=args.window_ms, max_batch=args.max_batch,
-            cache_size=args.cache_size, cache_bits=args.cache_bits,
-            passes=args.passes, deadline_ms=args.deadline_ms,
-            max_queue=args.max_queue, degraded_ok=args.degraded_ok,
-            chaos_spec=args.chaos, ingest_rate=args.ingest_rate,
-            obs=hub, compound=args.compound, feedback=args.feedback,
-            replicas=args.replicas, hedge_ms=args.hedge_ms,
-            heartbeat_ms=args.heartbeat_ms)
-    else:
-        serve_sequential(corpus, estimators, queries, seed=args.seed,
-                         obs=hub, compound=args.compound,
-                         feedback=args.feedback)
+    profile = (jax.profiler.trace(args.profile_dir) if args.profile_dir
+               else contextlib.nullcontext())
+    with profile:
+        if args.concurrency > 1:
+            stats = serve_concurrent(
+                corpus, estimators, queries, est_name=args.estimator,
+                seed=args.seed, concurrency=args.concurrency,
+                window_ms=args.window_ms, max_batch=args.max_batch,
+                cache_size=args.cache_size, cache_bits=args.cache_bits,
+                passes=args.passes, deadline_ms=args.deadline_ms,
+                max_queue=args.max_queue, degraded_ok=args.degraded_ok,
+                chaos_spec=args.chaos, ingest_rate=args.ingest_rate,
+                obs=hub, compound=args.compound, feedback=args.feedback,
+                replicas=args.replicas, hedge_ms=args.hedge_ms,
+                heartbeat_ms=args.heartbeat_ms)
+        else:
+            serve_sequential(corpus, estimators, queries, seed=args.seed,
+                             obs=hub, compound=args.compound,
+                             feedback=args.feedback)
+    if args.profile_dir:
+        print(f"profiler trace -> {args.profile_dir}")
     is_fleet = stats is not None and "replicas" in stats
     snap = obs_report.build_snapshot(
         registry=hub.registry,

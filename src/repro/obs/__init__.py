@@ -8,6 +8,9 @@ The serving stack's sensor layer (docs/observability.md):
     ``--metrics-json``).
   * ``Tracer`` — sampled JSONL per-request trace spans
     (``serve --trace-out PATH --trace-sample N``).
+  * ``spans`` — profiler spans at the layer boundaries, on the device
+    trace's clock (``serve --profile-dir``); always on, under a
+    microsecond each outside a profiler session.
   * ``ObsHub`` — the single handle (registry + tracer) threaded through
     coalescer / serve / chaos / index / plan execution.
   * ``report`` — the canonical snapshot schema and the unified exit

@@ -5,7 +5,8 @@ take a single ``obs`` argument/attribute. Everything is duck-typed at
 the call sites (the index layer never imports this module — it just
 calls ``self.obs.index_scan(...)`` when an obs handle was attached), so
 layering stays: core/index/runtime know nothing about obs, launch wires
-it.
+it. The one exception is ``repro.obs.spans``: it imports only jax, and
+core/ and index/ open its profiler spans at their layer boundaries.
 
 Accuracy accounting (``record_plan``): after a plan executes, the true
 selectivity of every filter is known for free (the observation behind
