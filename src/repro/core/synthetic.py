@@ -36,7 +36,9 @@ class Concept:
     children: list[int]
     direction: np.ndarray          # unit vector
     name: str
-    leaf_image_ids: np.ndarray     # all images in subtree (filled post-build)
+    # all images in the subtree (filled post-build), sorted ascending:
+    # Corpus.vlm_answer looks sample ids up in it by binary search
+    leaf_image_ids: np.ndarray
 
 
 @dataclasses.dataclass
@@ -88,10 +90,26 @@ class Corpus:
         Asymmetric error profile: misses (yes->no) at ``vlm_error``, false
         positives at ``vlm_error/8`` — VLM precision on specific "Is X
         depicted?" prompts is much higher than recall (the paper observes
-        exactly this miss-dominated behaviour on wildlife, §4.2)."""
-        truth = np.zeros(len(self.images), bool)
-        truth[self.true_matches(node_id)] = True
-        ans = truth[image_ids]
+        exactly this miss-dominated behaviour on wildlife, §4.2).
+
+        ``image_ids`` are row ids in ``[0, N)``, in any order, repeats
+        allowed. The truth lookup adapts to their number: a few ids (a
+        calibration sample) are binary-searched in the sorted match list,
+        O(len(ids) log M); many ids (a cascade's survivors) read an N-row
+        mask, whose fill cost does not grow with the ids. Both give the same
+        bits. The cut-over, 1 id per 512 rows, is where the two cost the
+        same on a CPU for small match lists at N = 2**20."""
+        m = self.true_matches(node_id)
+        if len(image_ids) * 512 <= len(self.images):
+            if len(m) == 0:
+                ans = np.zeros(len(image_ids), bool)
+            else:
+                pos = np.minimum(np.searchsorted(m, image_ids), len(m) - 1)
+                ans = m[pos] == image_ids
+        else:
+            truth = np.zeros(len(self.images), bool)
+            truth[m] = True
+            ans = truth[image_ids]
         g = np.random.default_rng(node_id * 104729 + seed)
         u = g.random(len(image_ids))
         fn = ans & (u < self.vlm_error)
